@@ -20,8 +20,12 @@ by construction. What the bench verifies and records:
     core-slicing penalty, which is why the JSON states the bound rather
     than asserting against it.
 
-Each scenario runs in a subprocess so the device count is set before jax
-initializes. ``make bench-distributed`` writes ``BENCH_distributed.json``.
+Each scenario runs in a subprocess with ``JAX_PLATFORMS=cpu`` forced, so
+the device count is set before jax initializes. That makes this a CPU
+rehearsal of the sharded path, not a chip path: a chip belongs to one
+process, and a child cannot reach it while a parent holds it. The chip path
+of the same serving code is ``python chip_smoke.py --chips 4``.
+``make bench-distributed`` writes ``BENCH_distributed.json``.
 
     PYTHONPATH=src python benchmarks/bench_distributed.py --smoke
     PYTHONPATH=src python benchmarks/bench_distributed.py \

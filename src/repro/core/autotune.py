@@ -42,6 +42,7 @@ import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core import lmma
+from repro.core.packing import chunk_groups
 from repro.core.lmma import (LMMADescriptor, TileSchedule, fused_tile_bytes,
                              select_fusion)
 
@@ -51,10 +52,11 @@ __all__ = ["TunedConfig", "TuningCache", "shape_key", "candidate_configs",
 
 CACHE_FORMAT_VERSION = 1
 
-# block-shape candidate axes (the scheduler's own lattice)
-_BM_CANDS = (8, 16, 32, 64, 128, 256)
+# block-shape candidate axes (the scheduler's own lattice; bg in packing
+# chunks, see lmma.align_blocks)
+_BM_CANDS = (32, 64, 128, 256)
 _BN_CANDS = (128, 256, 512, 1024, 2048)
-_BG_CANDS = (8, 16, 32, 64, 128, 256, 512)
+_BG_CHUNKS = (1, 2, 4, 8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,25 +88,17 @@ def shape_key(m: int, n: int, g: int, k_group: int, w_bits: int, *,
             f"{dtype}.tq{table_quant or 'none'}")
 
 
-def _realign_bg(bg: int, planes: int, k_group: int) -> int:
-    """Packed-stream byte alignment (same rule as ops._clamp_blocks)."""
-    bg = max(1, int(bg))
-    while (bg * planes * k_group) % 8:
-        bg *= 2
-    return bg
-
-
 def sanitize_config(cfg: TunedConfig, m: int, n: int, g: int, k_group: int,
                     planes: int,
-                    vmem_budget: int = lmma.VMEM_BYTES) -> Optional[TunedConfig]:
+                    vmem_budget: int = lmma.TILE_BUDGET) -> Optional[TunedConfig]:
     """Force a (possibly foreign) cache entry into a valid dispatch decision.
 
     Returns None when the entry is unusable (bad types / non-positive
-    blocks / unknown fusion); otherwise clamps blocks to the problem,
-    re-applies the packed-stream byte alignment, and demotes ``fused`` to
+    blocks / unknown fusion); otherwise rounds the blocks to the TPU tiling
+    rule, clamps them to the padded problem, and demotes ``fused`` to
     ``staged`` when the fused working set cannot fit VMEM — the exact
-    constraints ops._clamp_blocks / select_fusion enforce, so a sanitized
-    config can never crash the wrappers.
+    constraints ops._clamp_blocks (lmma.align_blocks) / select_fusion
+    enforce, so a sanitized config can never crash the wrappers.
     """
     try:
         bm, bn, bg = int(cfg.block_m), int(cfg.block_n), int(cfg.block_g)
@@ -113,9 +107,7 @@ def sanitize_config(cfg: TunedConfig, m: int, n: int, g: int, k_group: int,
         return None
     if fusion not in ("fused", "staged") or bm <= 0 or bn <= 0 or bg <= 0:
         return None
-    bm = min(bm, max(8, m))
-    bn = min(bn, max(1, n))
-    bg = _realign_bg(min(bg, max(1, g)), planes, k_group)
+    bm, bn, bg = lmma.align_blocks(m, n, g, k_group, planes, bm, bn, bg)
     desc = LMMADescriptor(m=m, n=n, k=g * k_group, w_bits=planes,
                           k_group=k_group)
     if fusion == "fused" and fused_tile_bytes(bm, bn, bg, desc) > vmem_budget:
@@ -311,7 +303,7 @@ def lookup_fusion_any(m: int, g: int, k_group: int, w_bits: int) -> Optional[str
 # ---------------------------------------------------------------------------
 
 def candidate_configs(m: int, n: int, g: int, k_group: int, planes: int, *,
-                      vmem_budget: int = lmma.VMEM_BYTES,
+                      vmem_budget: int = lmma.TILE_BUDGET,
                       max_candidates: int = 6) -> List[TunedConfig]:
     """Analytically-ranked search space for one mpGEMM shape.
 
@@ -328,12 +320,12 @@ def candidate_configs(m: int, n: int, g: int, k_group: int, planes: int, *,
                           k_group=k_group)
     scored = []
     seen = set()
-    for bm in (c for c in _BM_CANDS if c <= max(m, 8)):
-        for bn in (c for c in _BN_CANDS if c <= max(n, _BN_CANDS[0])):
-            for bg in (c for c in _BG_CANDS if c <= max(g, _BG_CANDS[0])):
-                bg = _realign_bg(min(bg, max(1, g)), planes, k_group)
-                bmc = min(bm, max(8, m))
-                bnc = min(bn, max(1, n))
+    chunk = chunk_groups(k_group, planes)
+    for bm in _BM_CANDS:
+        for bn in _BN_CANDS:
+            for f in _BG_CHUNKS:
+                bmc, bnc, bg = lmma.align_blocks(m, n, g, k_group, planes,
+                                                 bm, bn, f * chunk)
                 if (bmc, bnc, bg) in seen:
                     continue
                 seen.add((bmc, bnc, bg))
@@ -346,8 +338,6 @@ def candidate_configs(m: int, n: int, g: int, k_group: int, planes: int, *,
     scored.sort(key=lambda s: -s[0])
 
     hm, hn, hg = pick_blocks(m, n, g, k_group, planes)
-    hm, hn, hg = (min(hm, max(8, m)), min(hn, max(1, n)),
-                  _realign_bg(min(hg, max(1, g)), planes, k_group))
     hfusion = select_fusion(desc, TileSchedule(hm, hn, hg, 0, 0, 0, 0),
                             vmem_budget=vmem_budget)
     out = [TunedConfig(hfusion, hm, hn, hg, source="heuristic")]

@@ -9,7 +9,8 @@ W-side (packed code bytes) of an mpGEMM tile have wildly different densities.
 
 The scheduler objective mirrors Roller's rTile logic: choose the largest
 (bm, bn, bg) whose working set fits the VMEM budget, with bn elongated
-(table-reuse, §3.2.2) and hardware-aligned lane dims (multiples of 128).
+(table-reuse, §3.2.2). Every candidate obeys the TPU tiling rule on every
+kernel operand (:func:`align_blocks`).
 """
 
 from __future__ import annotations
@@ -17,12 +18,47 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["LMMADescriptor", "TileSchedule", "schedule_tiles", "lmma_name",
-           "fused_tile_bytes", "select_fusion"]
+from repro.core.packing import LANES as LANE, chunk_groups
+from repro.roofline import hw
 
-VMEM_BYTES = 64 * 1024 * 1024  # v5e VMEM ~128MB/2 cores -> 64MB usable/core
-LANE = 128
-SUBLANE = 8
+__all__ = ["LMMADescriptor", "TileSchedule", "schedule_tiles", "lmma_name",
+           "fused_tile_bytes", "select_fusion", "align_blocks", "VMEM_BYTES",
+           "TILE_BUDGET"]
+
+# the scoped-VMEM limit every pallas_call passes to the compiler: the whole
+# VMEM of the target chip (one TensorCore per v5e chip)
+VMEM_BYTES = hw.spec(hw.TARGET_KIND).vmem_bytes
+# what the scheduler lets the double-buffered blocks take; the other half is
+# for in-kernel temporaries (unpacked fields, CW tiles) and compiler scratch
+TILE_BUDGET = VMEM_BYTES // 2
+# block rows: int8 operands (tables) tile (32, 128), the strictest of the
+# kernels' dtypes, so 32 also satisfies f32 (8) and bf16 (16) operands
+SUBLANE = 32
+
+
+def round_up(x: int, mult: int) -> int:
+    """Smallest multiple of ``mult`` that is >= max(x, 1)."""
+    return -(-max(1, int(x)) // mult) * mult
+
+
+def align_blocks(m: int, n: int, g: int, k_group: int, planes: int,
+                 bm: int, bn: int, bg: int) -> Tuple[int, int, int]:
+    """The TPU tiling rule for every kernel operand, as one block choice.
+
+    Operand blocks (last two dims): activations ``(bm, bg·K)``, tables
+    ``(bm, bg·E)`` int8/f32, row scale ``(bm, 1)``, group scale
+    ``(bm, bg)``, packed weights ``(bn, bg·B·K/8)`` uint8, weight scale
+    ``(1, bn)`` and output ``(bm, bn)`` f32. So bm is a multiple of 32 (int8
+    rows), bn of 128 lanes, and bg of the packing chunk (a multiple of 128
+    groups whose packed bytes fill whole 128-lane columns). Blocks round
+    *up* — the wrappers pad the activation side instead of shrinking a block
+    below the tile — and are clamped to the padded problem.
+    """
+    c = chunk_groups(k_group, planes)
+    bm = min(round_up(bm, SUBLANE), round_up(m, SUBLANE))
+    bn = min(round_up(bn, LANE), round_up(n, LANE))
+    bg = min(round_up(bg, c), round_up(g, c))
+    return bm, bn, bg
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,14 +114,18 @@ def _tile_bytes(bm, bn, bg, desc: LMMADescriptor) -> Tuple[int, int, int]:
 
 
 def schedule_tiles(desc: LMMADescriptor,
-                   vmem_budget: int = VMEM_BYTES,
+                   vmem_budget: int = TILE_BUDGET,
                    elongate: bool = True) -> TileSchedule:
     """Pick (bm, bn, bg) by memory size (§3.3.2) with elongated N (§3.2.2)."""
-    g_total = desc.k / desc.k_group
+    g_total = desc.k // desc.k_group
+    planes = desc.w_bits if desc.w_bits > 0 else 2
+    c = chunk_groups(desc.k_group, planes)
     best: Optional[TileSchedule] = None
-    bm_cands = [m for m in (8, 16, 32, 64, 128, 256) if m <= max(desc.m, 8)]
-    bn_cands = [n for n in (128, 256, 512, 1024, 2048) if n <= max(desc.n, LANE)]
-    bg_cands = [g for g in (8, 16, 32, 64, 128, 256, 512) if g <= max(g_total, 8)]
+    bm_cands = [m for m in (32, 64, 128, 256)
+                if m <= round_up(desc.m, SUBLANE)]
+    bn_cands = [n for n in (128, 256, 512, 1024, 2048)
+                if n <= round_up(desc.n, LANE)]
+    bg_cands = [c * f for f in (1, 2, 4, 8) if c * f <= round_up(g_total, c)]
     for bm in bm_cands:
         for bn in bn_cands:
             for bg in bg_cands:
@@ -99,8 +139,8 @@ def schedule_tiles(desc: LMMADescriptor,
                 if best is None or _score(cand, desc, elongate) > _score(best, desc, elongate):
                     best = cand
     if best is None:
-        t, w, a = _tile_bytes(8, LANE, 8, desc)
-        best = TileSchedule(8, LANE, 8, t, w, a, 2 * (t + w) + a)
+        t, w, a = _tile_bytes(SUBLANE, LANE, c, desc)
+        best = TileSchedule(SUBLANE, LANE, c, t, w, a, 2 * (t + w) + a)
     return best
 
 
@@ -127,7 +167,7 @@ def fused_tile_bytes(bm: int, bn: int, bg: int, desc: LMMADescriptor) -> int:
 
 def select_fusion(desc: LMMADescriptor,
                   ts: Optional[TileSchedule] = None,
-                  vmem_budget: int = VMEM_BYTES) -> str:
+                  vmem_budget: int = TILE_BUDGET) -> str:
     """§3.1.1 fusion decision: 'fused' iff the table block fits VMEM.
 
     The fused kernel never writes the [M, G·E] table to HBM, but pays an

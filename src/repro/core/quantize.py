@@ -3,8 +3,9 @@
 ``QuantizedWeight`` is the single weight container consumed by every mpGEMM
 mode (dequant / lut_xla / lut_pallas) and by the serving stack:
 
-  * ``packed``       uint8 [N, ceil(K*B/8)] — folded group codes (Eq. 6
-                     applied offline), the true B-bit HBM format,
+  * ``packed``       uint8 [N, Gp*B*k_group/8] — folded group codes (Eq. 6
+                     applied offline), the B-bit HBM format (groups padded
+                     to whole packing chunks, see core/packing.py),
   * ``scale``        float32 [N]            — s' = s/2 (reinterpreted),
   * ``zero_prime``   float32 [N] or None    — z' (None ⇒ symmetric, z'=0),
   * ``plane_scales`` float32 [B]            — [1,2,4..] or [1,1] (ternary),
@@ -117,9 +118,10 @@ class QuantizedWeight:
     def sign_idx(self):
         """Unpack to (sign, idx) uint8 [N, G, B].
 
-        The packed byte stream is group-major ((g, b) at field g*B + b), so
-        a plane-sliced view CANNOT truncate bytes: unpack at the stored
-        plane count, then slice this view's plane range.
+        The packed stream interleaves the planes of each 128-group lane
+        vector (slot j*B + b, core/packing.py), so a plane-sliced view
+        CANNOT truncate bytes: unpack at the stored plane count, then slice
+        this view's plane range.
         """
         sign, idx = packing.unpack_group_codes(
             self.packed, self.k_group, self.g, self.stored_planes)

@@ -18,14 +18,13 @@ whole lookup run as one int8 GEMM — see DESIGN.md §2).
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["Table", "sign_basis", "precompute_table", "quantize_table", "table_entries"]
+__all__ = ["Table", "signed_entries", "precompute_table", "quantize_table",
+           "table_entries"]
 
 
 class Table(NamedTuple):
@@ -43,31 +42,39 @@ class Table(NamedTuple):
     k_group: int
 
 
-@functools.lru_cache(maxsize=None)
-def _sign_basis_np(k_group: int) -> np.ndarray:
-    """[K, E] ±1 basis: column e holds (σ_0..σ_{K-1}) with σ_{K-1} = -1."""
-    e = 1 << (k_group - 1)
-    basis = np.empty((k_group, e), dtype=np.float32)
-    ent = np.arange(e)
-    for i in range(k_group - 1):
-        basis[i] = 2.0 * ((ent >> i) & 1) - 1.0
-    basis[k_group - 1] = -1.0
-    return basis
+def signed_entries(parts, k_group: int):
+    """Half-table entries from the K group positions, one array per entry.
 
-
-def sign_basis(k_group: int) -> jax.Array:
-    return jnp.asarray(_sign_basis_np(k_group))
+    ``parts[i]`` holds position i of every group (any common shape);
+    returns ``[T[0], ..., T[E-1]]`` with ``T[e] = Σ_i σ_i(e)·parts[i]``
+    summed in position order. The ±1 products are exact, so this fixed
+    order makes the oracle and the Pallas kernels (which call it on
+    ``[bm, 128]`` lane tiles) bit-identical — and keeps the table exact
+    float32 on backends whose default matmul precision is lower.
+    """
+    out = []
+    for e in range(1 << (k_group - 1)):
+        t = None
+        for i, a in enumerate(parts):
+            neg = i == k_group - 1 or not (e >> i) & 1
+            t = (-a if neg else a) if t is None else (t - a if neg else t + a)
+        out.append(t)
+    return out
 
 
 def table_entries(a_groups: jax.Array, k_group: int) -> jax.Array:
-    """[..., G, K] activations -> [..., G, E] half-table entries.
+    """[..., G, K] activations -> [..., G, E] half-table entries."""
+    a = a_groups.astype(jnp.float32)
+    parts = [a[..., i] for i in range(k_group)]
+    return jnp.stack(signed_entries(parts, k_group), axis=-1)
 
-    One matmul against the ±1 basis; on TPU this runs on the MXU and is the
-    natural fusion target after the preceding element-wise op.
-    """
-    return jnp.einsum(
-        "...gk,ke->...ge", a_groups.astype(jnp.float32), sign_basis(k_group)
-    )
+
+def abs_sum(parts):
+    """Σ_i |parts[i]| in position order (see :func:`group_absmax`)."""
+    t = jnp.abs(parts[0])
+    for a in parts[1:]:
+        t = t + jnp.abs(a)
+    return t
 
 
 def group_absmax(a_groups: jax.Array) -> jax.Array:
@@ -77,7 +84,8 @@ def group_absmax(a_groups: jax.Array) -> jax.Array:
     E) lets the per-row scale be computed from A *before* the table exists —
     the kernel and the oracle share it bit-exactly.
     """
-    return jnp.sum(jnp.abs(a_groups.astype(jnp.float32)), axis=-1)  # [..., G]
+    a = a_groups.astype(jnp.float32)
+    return abs_sum([a[..., i] for i in range(a.shape[-1])])  # [..., G]
 
 
 def precompute_table(

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed._compat import shard_map
 
 __all__ = ["pipelined_forward", "split_stages"]
 
@@ -81,7 +80,7 @@ def pipelined_forward(
         return buf
 
     spec_p = jax.tree.map(lambda _: P(pp_axis), staged_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(spec_p, P()), out_specs=P(),
         check_vma=False)

@@ -22,7 +22,9 @@ child paths (".../qw/packed" etc.), and their rules mirror the float ones:
 a column-parallel float weight [K, N] sharded ("fsdp", "model") becomes a
 packed plane [N, ceil(K·B/8)] sharded ("model", None) — the quantizer packs
 output-major — while a row-parallel weight shards the byte dim, which is
-only legal on bit-group boundaries (see :func:`resolve_physical_spec`).
+only legal on whole packing chunks (see :func:`resolve_physical_spec`);
+:func:`pad_row_parallel` pads a packed weight to a chunk count the plan
+divides.
 """
 
 from __future__ import annotations
@@ -35,11 +37,13 @@ import threading
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["AxisPlan", "plan_scope", "current_plan", "shard",
            "param_spec_tree", "named_sharding_tree", "constrain_tree",
-           "resolve_physical_spec", "packed_group_bytes", "DEFAULT_RULES"]
+           "resolve_physical_spec", "packed_group_bytes", "pad_row_parallel",
+           "DEFAULT_RULES"]
 
 _state = threading.local()
 
@@ -121,7 +125,8 @@ def shard(x, *logical_axes):
 #
 # Quantized leaves: QuantizedWeight flattens to named children, so packed
 # serving trees yield paths "layers/attn/wq/qw/packed" / ".../qw/scale" /
-# ".../qw/zero_prime" / ".../qw/cw". packed is uint8 [N, ceil(K·B/8)] with
+# ".../qw/zero_prime" / ".../qw/cw". packed is uint8 [N, Gp·B·k_group/8]
+# (groups padded to whole packing chunks, core/packing.py) with
 # N = d_out (the quantizer consumes w.T), scale/zero_prime are [N], and cw
 # is the offline combined-lookup matrix [G·E, N] (group-major rows, so a
 # K-shard is a contiguous row block).
@@ -234,11 +239,14 @@ def param_spec_tree(params, rules=None):
 
 
 def packed_group_bytes(qw) -> int:
-    """Bytes one k-group occupies in a packed plane row — the granularity
-    below which the byte dim of ``packed`` must never be split."""
-    g = max(1, qw.k_total // qw.k_group)
-    last = qw.packed.shape[-1] if qw.packed is not None else 0
-    return max(1, last // g) if last % g == 0 and last else 1
+    """Bytes one packing chunk (``packing.chunk_groups`` groups, all stored
+    planes) occupies in a packed row — the granularity below which the byte
+    dim of ``packed`` must never be split."""
+    from repro.core.packing import chunk_groups
+    if qw.packed is None:
+        return 1
+    planes = qw.stored_planes
+    return chunk_groups(qw.k_group, planes) * planes * qw.k_group // 8
 
 
 def resolve_physical_spec(shape, phys_axes, axis_sizes,
@@ -284,6 +292,36 @@ def _packed_align_map(params):
         params, is_leaf=lambda x: isinstance(x, QuantizedWeight))
     return {_path_str(path): packed_group_bytes(leaf)
             for path, leaf in flat if isinstance(leaf, QuantizedWeight)}
+
+
+def pad_row_parallel(params, plan: AxisPlan, rules=None):
+    """Append zero packing chunks to every packed weight whose byte dim the
+    plan shards over a count that does not divide its chunks, so its K
+    splits instead of replicating (qwen2-72b ``mlp/down``: K=29568 is 58
+    chunks, 60 at TP=4). Nothing reads the padding: unpacking and the
+    kernels stop at the true group count."""
+    from repro.core.quantize import QuantizedWeight
+    rules = rules or DEFAULT_RULES
+
+    def pad(path, node):
+        if not isinstance(node, QuantizedWeight) or node.packed is None:
+            return node
+        packed = node.packed
+        spec = _spec_for(_path_str(path) + "/packed", packed.shape, rules)
+        shards = plan.axis_size(spec[-1]) if spec else 1
+        chunk = packed_group_bytes(node)
+        extra = -(packed.shape[-1] // chunk) % shards
+        if not extra:
+            return node
+        widths = [(0, 0)] * (packed.ndim - 1) + [(0, extra * chunk)]
+        return QuantizedWeight(
+            jnp.pad(packed, widths), node.scale, node.zero_prime,
+            node.plane_scales, bits=node.bits, k_group=node.k_group,
+            k_total=node.k_total, n=node.n, cw=node.cw,
+            plane_start=node.plane_start, stored_planes=node.stored_planes)
+
+    return jax.tree_util.tree_map_with_path(
+        pad, params, is_leaf=lambda x: isinstance(x, QuantizedWeight))
 
 
 def named_sharding_tree(params, plan: AxisPlan, rules=None):

@@ -7,7 +7,6 @@ outside the kernel), and block-shape selection via the LMMA tile scheduler.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -16,8 +15,10 @@ import jax.numpy as jnp
 from repro.core import autotune
 from repro.core import table as table_mod
 from repro.obs import dispatch as dispatch_obs
-from repro.core.lmma import (LMMADescriptor, TileSchedule, schedule_tiles,
+from repro.core.lmma import (SUBLANE, LMMADescriptor, TileSchedule,
+                             align_blocks, round_up, schedule_tiles,
                              select_fusion)
+from repro.core.packing import LANES
 from repro.core.quantize import QuantizedWeight
 from repro.core.table import Table
 from repro.kernels import ref
@@ -43,16 +44,11 @@ def _pad_to(x, mult, axis):
 
 
 def pick_blocks(m, n, g, k_group, planes, max_bm=256, max_bn=512, max_bg=512):
-    """Block shapes: scheduler-elongated but clamped to (padded) problem."""
+    """Block shapes: scheduler-elongated, capped, tile-aligned."""
     desc = LMMADescriptor(m=m, n=n, k=g * k_group, w_bits=planes, k_group=k_group)
     ts = schedule_tiles(desc)
-    bm = min(ts.bm, max_bm)
-    bn = min(ts.bn, max_bn)
-    bg = min(ts.bg, max_bg)
-    # keep K-blocks byte-aligned for the packed stream
-    while (bg * planes * k_group) % 8:
-        bg *= 2
-    return bm, bn, bg
+    return align_blocks(m, n, g, k_group, planes, min(ts.bm, max_bm),
+                        min(ts.bn, max_bn), min(ts.bg, max_bg))
 
 
 def _closed_form_row_scale(a: jax.Array, g: int, k_group: int) -> jax.Array:
@@ -67,22 +63,15 @@ def _closed_form_row_scale(a: jax.Array, g: int, k_group: int) -> jax.Array:
 
 
 def _clamp_blocks(m, n, g, k_group, planes, block_m, block_n, block_g):
-    """Block shapes clamped to the (padded) problem, byte-realigned.
-
-    Clamping bg to a small/odd g can undo the alignment pick_blocks
-    established, so the packed-stream byte alignment is re-applied after
-    every clamp. Shared by every mpGEMM wrapper.
-    """
+    """Block shapes for one call: the caller's where given, the scheduler's
+    otherwise, then rounded up to the tiling rule (lmma.align_blocks) and
+    clamped to the padded problem. Shared by every mpGEMM wrapper."""
     if block_m is None or block_n is None or block_g is None:
         bm, bn, bg = pick_blocks(m, n, g, k_group, planes)
     else:
         bm = bn = bg = None  # all supplied; skip the scheduler search
-    bm = block_m or min(bm, max(8, m))
-    bn = block_n or min(bn, n)
-    bg = block_g or min(bg, g)
-    while (bg * planes * k_group) % 8:
-        bg *= 2
-    return bm, bn, bg
+    return align_blocks(m, n, g, k_group, planes, block_m or bm,
+                        block_n or bn, block_g or bg)
 
 
 def auto_fusion(m, n, g, k_group, planes,
@@ -190,20 +179,46 @@ def _padded_row_scale(a: jax.Array, g: int, k_group: int, bm: int):
     return jnp.where(rs == 0, 1.0, rs)  # padded rows get an inert scale
 
 
-def _pad_packed(qw: QuantizedWeight, gp: int, bn: int):
-    """Pad packed codes to gp K-groups / bn N-rows; pad wscale alongside.
+def _lane_groups(x: jax.Array, gt: int, k_group: int) -> jax.Array:
+    """[M, K] -> [M, gt·K] kernel activation layout: column
+    ``(j·K + i)·128 + l`` holds position i of group ``j·128 + l`` (groups
+    zero-padded to gt, a multiple of 128). Zero groups make zero table
+    entries, so padding is inert."""
+    m = x.shape[0]
+    x = jnp.pad(x, ((0, 0), (0, gt * k_group - x.shape[1])))
+    x = jnp.swapaxes(x.reshape(m, gt // LANES, LANES, k_group), 2, 3)
+    return x.reshape(m, gt * k_group)
 
-    NOTE: padded K-groups decode from zero bytes to sign=0, idx=0 fields, so
-    CW is nonzero at entry 0 — but the corresponding *table values* are 0
-    (A is zero-padded), so padded groups contribute 0 regardless of CW.
-    """
-    pkp = qw.packed
-    pb_full = gp * qw.num_planes * qw.k_group // 8
-    if pkp.shape[1] < pb_full:
-        pkp = jnp.pad(pkp, ((0, 0), (0, pb_full - pkp.shape[1])))
-    pkp = _pad_to(pkp, bn, 0)
-    wsp = _pad_to(qw.scale.astype(jnp.float32), bn, 0)
-    return pkp, wsp
+
+def _table_to_kernel_layout(values: jax.Array, gt: int) -> jax.Array:
+    """Logical [M, G, E] table -> kernel layout [M, gt·E] (entry-major per
+    128-group lane vector, see table_precompute.py)."""
+    m, g, e = values.shape
+    v = jnp.pad(values, ((0, 0), (0, gt - g), (0, 0)))
+    return jnp.swapaxes(v.reshape(m, gt // LANES, LANES, e), 2, 3).reshape(
+        m, gt * e)
+
+
+def _table_from_kernel_layout(values: jax.Array, g: int, e: int) -> jax.Array:
+    m = values.shape[0]
+    v = values.reshape(m, -1, e, LANES)
+    return jnp.swapaxes(v, 2, 3).reshape(m, -1, e)[:, :g]
+
+
+def _precompute(x: jax.Array, k_group: int, table_quant: Optional[str],
+                bm: int, bg: int, interpret: bool):
+    """Staged precompute in the kernel layout, rows padded to bm and groups
+    to a multiple of bg -> (values, scale): scale is the [Mp, 1] row scale
+    (per_row), the [Mp, Gt] group scale (per_group) or None."""
+    g = x.shape[1] // k_group
+    xl = _lane_groups(_pad_to(x, bm, 0), round_up(g, bg), k_group)
+    row_scale = None
+    if table_quant == "per_row":
+        row_scale = _padded_row_scale(x, g, k_group, bm)
+    values, gscale = table_precompute_pallas(
+        xl, k_group, table_quant, row_scale,
+        block_m=bm, block_g=bg, interpret=interpret)
+    return values, (row_scale if table_quant == "per_row" else gscale)
 
 
 def table_precompute(a: jax.Array, k_group: int = 4,
@@ -213,27 +228,16 @@ def table_precompute(a: jax.Array, k_group: int = 4,
     """Pallas-backed independent precompute operator (§3.1.1)."""
     m, k_total = a.shape
     g = k_total // k_group
-    block_m = min(block_m, m) if m % min(block_m, m) == 0 else block_m
-    ap = _pad_to(_pad_to(a, block_m, 0), 1, 1)
-    mp = ap.shape[0]
-    if block_g is None:
-        block_g = min(128, g)
-    gpad = (-g) % block_g
-    if gpad:
-        ap = jnp.pad(ap, ((0, 0), (0, gpad * k_group)))
-    rowsum = jnp.sum(a.astype(jnp.float32), axis=-1)
-    row_scale = None
-    if table_quant == "per_row":
-        row_scale = _padded_row_scale(a, g, k_group, block_m)
-    values, scale = table_precompute_pallas(
-        ap, k_group, table_quant, row_scale,
-        block_m=block_m, block_g=block_g, interpret=interpret)
     e = 1 << (k_group - 1)
-    values = values[:m, : g * e].reshape(m, g, e)
+    bm = min(round_up(block_m, SUBLANE), round_up(m, SUBLANE))
+    bg = min(round_up(block_g or 512, LANES), round_up(g, LANES))
+    values, scale = _precompute(a, k_group, table_quant, bm, bg, interpret)
+    values = _table_from_kernel_layout(values[:m], g, e)
+    rowsum = jnp.sum(a.astype(jnp.float32), axis=-1)
     if table_quant is None:
         return Table(values, None, rowsum, k_group)
     if table_quant == "per_row":
-        return Table(values, row_scale[:m].reshape(m, 1, 1), rowsum, k_group)
+        return Table(values, scale[:m].reshape(m, 1, 1), rowsum, k_group)
     return Table(values, scale[:m, :g].reshape(m, g, 1), rowsum, k_group)
 
 
@@ -245,8 +249,8 @@ def fused_lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
                      interpret: bool = False) -> jax.Array:
     """Single-kernel precompute→lookup mpGEMM: the table never leaves VMEM.
 
-    Streams activation blocks, rebuilds each [bm, bg·E] table block on the
-    MXU in-VMEM (quantizing in-register for per_row/per_group), and contracts
+    Streams activation blocks, rebuilds each [bm, 128] table tile in-VMEM
+    (quantizing in-register for per_row/per_group), and contracts it
     immediately against CW — the fused DFG of §3.1.1. Bit-exact with the
     staged ``table_precompute`` + ``lut_mpgemm`` composition on the per_row
     int8 path, float-tolerance-equal otherwise.
@@ -257,25 +261,17 @@ def fused_lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
     planes = qw.num_planes
     bm, bn, bg = _clamp_blocks(m, qw.n, g, qw.k_group, planes,
                                block_m, block_n, block_g)
-
     rowsum = jnp.sum(x.astype(jnp.float32), axis=-1)
     row_scale = None
     if table_quant == "per_row":
         row_scale = _padded_row_scale(x, g, qw.k_group, bm)
-
-    # pad activations to (bm, bg·K) blocks; zero rows/groups produce zero
-    # table entries, so padded blocks contribute nothing to the output
-    xp = _pad_to(_pad_to(x, bm, 0), bg * qw.k_group, 1)
-    gp = xp.shape[1] // qw.k_group
-    pkp, wsp = _pad_packed(qw, gp, bn)
-
+    xl = _lane_groups(_pad_to(x, bm, 0), round_up(g, bg), qw.k_group)
     out = fused_lut_mpgemm_pallas(
-        xp, row_scale, pkp, wsp, k_group=qw.k_group,
+        xl, row_scale, qw.packed, qw.scale, k_group=qw.k_group,
         table_quant=table_quant, planes=planes,
-        plane_scales=qw.plane_scales, n=pkp.shape[0],
+        plane_scales=qw.plane_scales,
         block_m=bm, block_n=bn, block_g=bg, interpret=interpret)
-    out = out[:m, :qw.n]
-    return ref.zero_point_correction(out, qw, rowsum)
+    return ref.zero_point_correction(out[:m], qw, rowsum)
 
 
 def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
@@ -302,7 +298,7 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
         raise ValueError(f"fusion {fusion!r} not in {FUSION_MODES}")
     _check_not_plane_sliced(qw, "lut_mpgemm")
     m = x.shape[0]
-    g, e = qw.g, 1 << (qw.k_group - 1)
+    g = qw.g
     planes = qw.num_planes
     fusion, bm, bn, bg = resolve_dispatch(
         m, qw.n, g, qw.k_group, planes, fusion=fusion, block_m=block_m,
@@ -312,30 +308,24 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
             x, qw, table_quant=table_quant, block_m=bm, block_n=bn,
             block_g=bg, interpret=interpret)
     if table is None:
-        table = table_precompute(x, qw.k_group, table_quant,
-                                 block_m=min(64, bm), interpret=interpret)
-    tv = table.values.reshape(m, g * e)
-    ts = None if table.scale is None else table.scale.reshape(m, -1)
-
-    # pad to block multiples
-    tvp = _pad_to(_pad_to(tv, bm, 0), bg * e, 1)
-    mp = tvp.shape[0]
-    gp = tvp.shape[1] // e
-    tsp = None
-    if ts is not None:
-        tsp = _pad_to(ts, bm, 0)
-        if ts.shape[1] != 1:  # per_group
-            tsp = _pad_to(tsp, bg, 1)
-        tsp = jnp.where(tsp == 0, 1.0, tsp)
-    pkp, wsp = _pad_packed(qw, gp, bn)
-    np_ = pkp.shape[0]
-
+        tv, ts = _precompute(x, qw.k_group, table_quant, bm, bg, interpret)
+        rowsum = jnp.sum(x.astype(jnp.float32), axis=-1)
+    else:
+        gt = round_up(g, bg)
+        tv = _pad_to(_table_to_kernel_layout(table.values, gt), bm, 0)
+        ts = None
+        if table.scale is not None:
+            ts = table.scale.reshape(m, -1)
+            if ts.shape[1] != 1:  # per_group
+                ts = jnp.pad(ts, ((0, 0), (0, gt - g)))
+            ts = jnp.pad(ts, ((0, tv.shape[0] - m), (0, 0)),
+                         constant_values=1.0)
+        rowsum = table.rowsum
     out = lut_mpgemm_pallas(
-        tvp, tsp, pkp, wsp, k_group=qw.k_group, planes=planes,
+        tv, ts, qw.packed, qw.scale, k_group=qw.k_group, planes=planes,
         plane_scales=qw.plane_scales,
-        n=np_, block_m=bm, block_n=bn, block_g=bg, interpret=interpret)
-    out = out[:m, :qw.n]
-    return ref.zero_point_correction(out, qw, table.rowsum)
+        block_m=bm, block_n=bn, block_g=bg, interpret=interpret)
+    return ref.zero_point_correction(out[:m], qw, rowsum)
 
 
 def dequant_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
@@ -344,19 +334,13 @@ def dequant_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
     _check_not_plane_sliced(qw, "dequant_mpgemm")
     m = x.shape[0]
     g = qw.g
-    planes = qw.num_planes
-    bm, bn, bg = _clamp_blocks(m, qw.n, g, qw.k_group, planes,
-                               min(block_m, max(8, m)), min(block_n, qw.n),
-                               min(block_g, g))
-    xp = _pad_to(_pad_to(x, bm, 0), bg * qw.k_group, 1)
-    mp, kp = xp.shape
-    gp = kp // qw.k_group
-    pkp, wsp = _pad_packed(qw, gp, bn)
+    bm, bn, bg = align_blocks(m, qw.n, g, qw.k_group, qw.num_planes,
+                              block_m, block_n, block_g)
+    xl = _lane_groups(_pad_to(x, bm, 0), round_up(g, bg), qw.k_group)
     out = dequant_mpgemm_pallas(
-        xp, pkp, wsp, k_group=qw.k_group, planes=planes,
-        plane_scales=qw.plane_scales,
-        n=pkp.shape[0], block_m=bm, block_n=bn, block_g=bg,
-        interpret=interpret)[:m, :qw.n]
+        xl, qw.packed, qw.scale, k_group=qw.k_group, planes=qw.num_planes,
+        plane_scales=qw.plane_scales, block_m=bm, block_n=bn, block_g=bg,
+        interpret=interpret)[:m]
     if qw.zero_prime is not None:
         rowsum = jnp.sum(x.astype(jnp.float32), axis=-1)
         out = ref.zero_point_correction(out, qw, rowsum)

@@ -80,19 +80,27 @@ def ref_lut_mpgemm_gather(a, qw: QuantizedWeight,
 
 
 def build_cw(qw: QuantizedWeight, dtype=jnp.int8):
-    if qw.cw is not None:
-        return qw.cw.astype(dtype)
     """Static combined-lookup weights CW [G*E, N].
 
     CW[(g,e), n] = Σ_b plane_scales[b] · (1-2·sign[n,g,b]) · [idx[n,g,b]==e].
     Integer plane scales (≤ Σ 2^b = 2^B-1 ≤ 15 for B≤4) keep CW exactly
-    representable in int8 — this is what unlocks the int8 MXU path.
+    representable in int8 — this is what unlocks the int8 MXU path. It is
+    built in int8 plane by plane: an int32 or one-hot [N, G, B, E]
+    intermediate would be 4-16x the size of CW itself (12.8 GiB for
+    qwen2-72b's LM head).
     """
+    if qw.cw is not None:
+        return qw.cw.astype(dtype)
     sign, idx = qw.sign_idx()  # [N, G, B]
     e = 1 << (qw.k_group - 1)
-    onehot = (idx[..., None] == jnp.arange(e, dtype=idx.dtype)).astype(jnp.int32)
-    coeff = (1 - 2 * sign.astype(jnp.int32)) * jnp.asarray(qw.plane_scales, jnp.int32)[None, None, :]
-    cw = jnp.einsum("ngbe,ngb->nge", onehot, coeff)  # [N, G, E]
+    ent = jnp.arange(e, dtype=idx.dtype)
+    cw = None
+    for b, ps in enumerate(qw.plane_scales):
+        coef = (int(ps) * (1 - 2 * sign[..., b].astype(jnp.int8))).astype(
+            jnp.int8)
+        term = jnp.where(idx[..., b, None] == ent, coef[..., None],
+                         jnp.int8(0))  # [N, G, E]
+        cw = term if cw is None else cw + term
     n, g = qw.n, qw.g
     return jnp.transpose(cw, (1, 2, 0)).reshape(g * e, n).astype(dtype)
 

@@ -1,10 +1,17 @@
 """Pallas TPU kernel: LUT table precompute (+ fused INT8 table quantization).
 
 The DFG-transformed precompute operator (§3.1.1) as a standalone kernel:
-activations stream HBM→VMEM once, each [bm, bg·K] block is contracted with
-the ±1 sign basis on the MXU to produce the [bm, bg·E] half-table block, and
-(optionally) quantized to INT8 in-VMEM before the store — so the table that
-lands in HBM is already LUT_BIT=8 (Eq. 7's table-size term).
+activations stream HBM→VMEM once, each block is turned into its half-table
+block on the VPU (exact ±1 signed sums), and (optionally) quantized to INT8
+in-VMEM before the store — so the table that lands in HBM is already
+LUT_BIT=8 (Eq. 7's table-size term).
+
+Kernel layout (``ops`` converts to and from it): groups ride the 128 lanes.
+The activation block holds, for lane-group sub-chunk j (groups
+``j*128 .. j*128+127``), the K group positions as K contiguous 128-lane
+tiles; the table block holds the E entries of sub-chunk j as E contiguous
+128-lane tiles (entry-major). Every load and store is a ``[bm, 128]`` tile
+at a 128-aligned lane offset — no reshape, which Mosaic cannot lower.
 
 Per-row scales are computed from A in closed form (Σ|a_i| per group, maxed
 over groups — see table.group_absmax) by the wrapper and passed in, so this
@@ -21,112 +28,121 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.core.lmma import VMEM_BYTES
+from repro.core.packing import LANES
+from repro.core.table import abs_sum, signed_entries
 
 __all__ = ["table_precompute_pallas"]
 
 
-def _sign_basis_iota(k_group: int):
-    """±1 basis [K, E] built from iota (pallas kernels cannot capture consts)."""
-    e = 1 << (k_group - 1)
-    ent = jax.lax.broadcasted_iota(jnp.int32, (k_group, e), 1)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (k_group, e), 0)
-    bit = (ent >> pos) & 1
-    basis = jnp.where(pos == k_group - 1, -1.0,
-                      2.0 * bit.astype(jnp.float32) - 1.0)
-    return basis
+def lane_tile(ref, start):
+    """``[rows, 128]`` tile of a VMEM block at a 128-aligned lane offset."""
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, LANES)
+    return ref[:, pl.ds(start, LANES)]
 
 
-def _kernel(a_ref, ts_ref, tq_ref, *, k_group: int, bm: int, bg: int,
-            mode: Optional[str]):
-    e = 1 << (k_group - 1)
-    a = a_ref[...].astype(jnp.float32).reshape(bm, bg, k_group)
-    basis = _sign_basis_iota(k_group)  # [K, E], materialized in VMEM
-    ent = jax.lax.dot_general(
-        a.reshape(bm * bg, k_group), basis, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(bm, bg, e)
-    if mode is None:
-        tq_ref[...] = ent.reshape(bm, bg * e)
+def store_lane_tile(ref, start, value):
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, LANES)
+    ref[:, pl.ds(start, LANES)] = value
+
+
+def for_each(n: int, body):
+    """body(i) for i in [0, n): a real loop, so code size is per-iteration."""
+    if n == 1:
+        body(0)
         return
-    if mode == "per_group":
-        absmax = jnp.sum(jnp.abs(a), axis=-1)  # [bm, bg] closed form
-        scale = jnp.maximum(absmax, 1e-30) / 127.0
-        ts_ref[...] = scale
-        q = ent / scale[:, :, None]
-    else:  # per_row: scale computed by wrapper, streamed in
-        q = ent / ts_ref[...].reshape(bm, 1, 1)
-    tq_ref[...] = jnp.clip(jnp.round(q), -127, 127).astype(jnp.int8).reshape(
-        bm, bg * e)
+
+    def step(i, carry):
+        body(i)
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
+
+
+def sub_chunk_entries(a_ref, j, k_group: int):
+    """Activation positions and half-table entries of lane sub-chunk j:
+    (K x [bm, 128], E x [bm, 128]) float32."""
+    parts = [lane_tile(a_ref, (j * k_group + i) * LANES).astype(jnp.float32)
+             for i in range(k_group)]
+    return parts, signed_entries(parts, k_group)
+
+
+def group_scale(parts):
+    """per_group INT8 scale of one sub-chunk: max_e|T[e]|/127 = Σ|a_i|/127."""
+    return jnp.maximum(abs_sum(parts), 1e-30) / 127.0
+
+
+def quantize_entries(ents, scale):
+    return [jnp.clip(jnp.round(t / scale), -127, 127).astype(jnp.int8)
+            for t in ents]
+
+
+def _kernel(a_ref, *refs, k_group: int, nsub: int, mode: Optional[str]):
+    e = 1 << (k_group - 1)
+    if mode == "per_row":
+        rs_ref, tq_ref = refs
+    elif mode == "per_group":
+        tq_ref, ts_ref = refs
+    else:
+        (tq_ref,) = refs
+
+    def body(j):
+        parts, ents = sub_chunk_entries(a_ref, j, k_group)
+        if mode == "per_row":
+            ents = quantize_entries(ents, rs_ref[...])
+        elif mode == "per_group":
+            scale = group_scale(parts)
+            store_lane_tile(ts_ref, j * LANES, scale)
+            ents = quantize_entries(ents, scale)
+        for i, t in enumerate(ents):
+            store_lane_tile(tq_ref, (j * e + i) * LANES, t)
+
+    for_each(nsub, body)
 
 
 def table_precompute_pallas(
-    a: jax.Array,             # [M, K_total] (pre-padded to blocks)
+    a: jax.Array,             # [M, Gt*K] lane-group layout (pre-padded)
     k_group: int,
     table_quant: Optional[str],
     row_scale: Optional[jax.Array] = None,  # [M, 1] f32, required for per_row
     *,
-    block_m: int = 64,
+    block_m: int = 32,
     block_g: int = 128,
     interpret: bool = False,
 ):
-    """Returns (values [M, G*E], scale or None). Rowsum is wrapper-side."""
-    m, k_total = a.shape
-    g = k_total // k_group
+    """Returns (values [M, Gt*E] kernel layout, per-group scale [M, Gt] or
+    None). Rowsum is wrapper-side."""
+    m, width = a.shape
+    g = width // k_group
     e = 1 << (k_group - 1)
-    assert m % block_m == 0 and g % block_g == 0, ((m, g), (block_m, block_g))
+    assert m % block_m == 0 and g % block_g == 0 and block_g % LANES == 0, (
+        (m, g), (block_m, block_g))
     grid = (m // block_m, g // block_g)
-    kern = functools.partial(_kernel, k_group=k_group, bm=block_m, bg=block_g,
-                             mode=table_quant)
-    out_dtype = jnp.float32 if table_quant is None else jnp.int8
-
+    kern = functools.partial(_kernel, k_group=k_group,
+                             nsub=block_g // LANES, mode=table_quant)
     in_specs = [pl.BlockSpec((block_m, block_g * k_group), lambda i, k: (i, k))]
+    args = [a]
     if table_quant == "per_row":
         assert row_scale is not None
         in_specs.append(pl.BlockSpec((block_m, 1), lambda i, k: (i, 0)))
-        ts_arg = row_scale.astype(jnp.float32)
-        out_specs = pl.BlockSpec((block_m, block_g * e), lambda i, k: (i, k))
-        out_shape = jax.ShapeDtypeStruct((m, g * e), out_dtype)
-        values = pl.pallas_call(
-            kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
-        )(a, ts_arg)
-        return values, row_scale
+        args.append(row_scale.astype(jnp.float32))
+    out_specs = [pl.BlockSpec((block_m, block_g * e), lambda i, k: (i, k))]
+    out_shape = [jax.ShapeDtypeStruct(
+        (m, g * e), jnp.float32 if table_quant is None else jnp.int8)]
     if table_quant == "per_group":
-        out_specs = [
-            pl.BlockSpec((block_m, block_g), lambda i, k: (i, k)),      # scale
-            pl.BlockSpec((block_m, block_g * e), lambda i, k: (i, k)),  # values
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((m, g), jnp.float32),
-            jax.ShapeDtypeStruct((m, g * e), out_dtype),
-        ]
-
-        def kern2(a_ref, ts_ref, tq_ref):
-            kern(a_ref, ts_ref, tq_ref)
-
-        scale, values = pl.pallas_call(
-            kern2, grid=grid, in_specs=in_specs[:1], out_specs=out_specs,
-            out_shape=out_shape,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
-            interpret=interpret,
-        )(a)
-        return values, scale
-    # float table
-    out_specs = pl.BlockSpec((block_m, block_g * e), lambda i, k: (i, k))
-    out_shape = jax.ShapeDtypeStruct((m, g * e), out_dtype)
-
-    def kern3(a_ref, tq_ref):
-        kern(a_ref, None, tq_ref)
-
-    values = pl.pallas_call(
-        kern3, grid=grid, in_specs=in_specs[:1], out_specs=out_specs,
+        out_specs.append(pl.BlockSpec((block_m, block_g), lambda i, k: (i, k)))
+        out_shape.append(jax.ShapeDtypeStruct((m, g), jnp.float32))
+    outs = pl.pallas_call(
+        kern, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
-    )(a)
-    return values, None
+        name="lut_table_precompute",
+    )(*args)
+    if table_quant == "per_group":
+        return outs[0], outs[1]
+    return outs[0], None

@@ -9,8 +9,18 @@ from __future__ import annotations
 
 from typing import Optional
 
-from repro.distributed._compat import make_mesh
+import jax
+from jax.sharding import AxisType
+
 from repro.distributed.sharding import AxisPlan
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules place
+    arrays with ``with_sharding_constraint``/``NamedSharding``, which only
+    address Auto axes (``jax.make_mesh`` defaults to Explicit ones)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -32,7 +42,6 @@ def make_serving_mesh(*, data: int = 1, model: Optional[int] = None):
     :func:`make_plan` yields a no-op plan (every axis has size 1, so every
     sharding constraint resolves to replication).
     """
-    import jax
     n = jax.device_count()
     if model is None:
         if n % max(1, data):

@@ -17,12 +17,14 @@ import numpy as np
 
 from repro.configs import registry
 from repro.core.mpgemm import FUSION_MODES, MPGEMM_MODES
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import api
 from repro.serving import decoding
 from repro.serving.engine import Request, ServingEngine
 
 
-def main(argv=None):
+def parser() -> argparse.ArgumentParser:
+    """The serving flags (shared by chip_smoke.py)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--reduced", action="store_true")
@@ -96,36 +98,47 @@ def main(argv=None):
                     help="record every mpGEMM dispatch decision (shape key, "
                          "fusion, tuned-vs-heuristic) traced during this "
                          "serve and write it as JSON")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def model_config(args):
+    """The served ArchConfig for parsed serving flags."""
     cfg = (registry.get_reduced(args.arch) if args.reduced
            else registry.get_config(args.arch))
     cfg = cfg.replace(activation_dtype=jnp.float32)
-    cfg = cfg.with_quant(mpgemm_mode=args.mode, weight_bits=args.weight_bits,
-                         fusion=args.fusion)
+    return cfg.with_quant(mpgemm_mode=args.mode,
+                          weight_bits=args.weight_bits, fusion=args.fusion)
 
-    print(f"init + quantize ({args.mode}, W{args.weight_bits}) ...")
+
+def load(args, cfg):
+    """Random weights (seed 0) in the serving format -> (cfg, params)."""
     quantized = args.mode != "fp16"
     params = api.init_params(jax.random.key(0), cfg,
                              serve_quantized=quantized)
     if not quantized:
         cfg = cfg.replace(quant=None)
+    return cfg, params
 
-    if args.fusion == "tuned" and args.tuning_cache is None and not args.pretune:
-        print("note: fusion=tuned without --tuning-cache falls back to the "
-              "auto heuristic on every dispatch")
+
+def build_engine(args, cfg, params, *, tracer=None, ap=None) -> ServingEngine:
+    """The ServingEngine exactly as ``main`` serves with these flags."""
+    def error(msg):
+        if ap is not None:
+            ap.error(msg)
+        raise ValueError(msg)
+
     if args.prefix_cache and args.cache_block_size is None:
-        ap.error("--prefix-cache requires --cache-block-size")
+        error("--prefix-cache requires --cache-block-size")
     try:
         dm = decoding.parse(args.decoding)
     except ValueError as e:
-        ap.error(str(e))
+        error(str(e))
     spec_draft_planes = dm.draft_planes if dm.kind == decoding.SPEC else None
     if spec_draft_planes is not None and args.mode == "fp16":
-        ap.error("--decoding spec needs a quantized mode: the draft is a "
-                 "bit-plane slice of the packed weights")
+        error("--decoding spec needs a quantized mode: the draft is a "
+              "bit-plane slice of the packed weights")
     if args.mesh is not None and args.tp is not None:
-        ap.error("--mesh and --tp are mutually exclusive")
+        error("--mesh and --tp are mutually exclusive")
     plan = None
     if args.mesh is not None or args.tp is not None:
         from repro.launch.mesh import make_plan, make_serving_mesh
@@ -133,13 +146,52 @@ def main(argv=None):
             try:
                 d, m = (int(v) for v in args.mesh.lower().split("x"))
             except ValueError:
-                ap.error(f"--mesh wants 'DxM' (e.g. 2x4), got {args.mesh!r}")
+                error(f"--mesh wants 'DxM' (e.g. 2x4), got {args.mesh!r}")
         else:
             d, m = 1, args.tp
         mesh = make_serving_mesh(data=d, model=m)
         plan = make_plan(mesh, fsdp=False)
         print(f"serving mesh {d}x{m} (data x model) over "
               f"{jax.device_count()} devices")
+    return ServingEngine(cfg, params, max_batch=args.max_batch,
+                         max_seq=args.max_seq,
+                         decode_chunk=args.decode_chunk,
+                         prefill_chunk=args.prefill_chunk,
+                         eos_id=args.eos_id,
+                         tuning_cache=args.tuning_cache,
+                         cache_block_size=args.cache_block_size,
+                         num_cache_blocks=args.num_cache_blocks,
+                         prefix_cache=args.prefix_cache,
+                         plan=plan,
+                         spec_k=args.spec_k,
+                         spec_draft_planes=spec_draft_planes,
+                         tracer=tracer)
+
+
+def make_requests(args, vocab_size: int):
+    """``--requests`` prompts of 4-23 random tokens (seed 0)."""
+    rng = np.random.default_rng(0)
+    out = []
+    for i in range(args.requests):
+        plen = int(rng.integers(4, 24))
+        out.append(Request(
+            uid=i, prompt=rng.integers(0, vocab_size, plen, dtype=np.int32),
+            max_new_tokens=args.max_new, temperature=args.temperature,
+            top_k=args.top_k, top_p=args.top_p, decoding=args.decoding))
+    return out
+
+
+def main(argv=None):
+    ap = parser()
+    args = ap.parse_args(argv)
+    configure_compile_cache()
+    cfg = model_config(args)
+    print(f"init + quantize ({args.mode}, W{args.weight_bits}) ...")
+    cfg, params = load(args, cfg)
+
+    if args.fusion == "tuned" and args.tuning_cache is None and not args.pretune:
+        print("note: fusion=tuned without --tuning-cache falls back to the "
+              "auto heuristic on every dispatch")
     tracer = None
     if args.trace_out is not None:
         from repro.obs.trace import Tracer
@@ -148,19 +200,7 @@ def main(argv=None):
     if args.dispatch_log is not None:
         from repro.obs import dispatch as dispatch_obs
         recorder = dispatch_obs.enable(dispatch_obs.DispatchRecorder())
-    eng = ServingEngine(cfg, params, max_batch=args.max_batch,
-                        max_seq=args.max_seq,
-                        decode_chunk=args.decode_chunk,
-                        prefill_chunk=args.prefill_chunk,
-                        eos_id=args.eos_id,
-                        tuning_cache=args.tuning_cache,
-                        cache_block_size=args.cache_block_size,
-                        num_cache_blocks=args.num_cache_blocks,
-                        prefix_cache=args.prefix_cache,
-                        plan=plan,
-                        spec_k=args.spec_k,
-                        spec_draft_planes=spec_draft_planes,
-                        tracer=tracer)
+    eng = build_engine(args, cfg, params, tracer=tracer, ap=ap)
     if args.pretune:
         if eng.tuning_cache is None:  # tune in-memory for this process
             from repro.core import autotune
@@ -169,13 +209,8 @@ def main(argv=None):
         n = eng.pretune(verbose=True)
         print(f"pretuned {n} mpGEMM shapes in {time.time() - t0:.1f}s "
               f"-> {args.tuning_cache or '(in-memory only)'}")
-    rng = np.random.default_rng(0)
-    for i in range(args.requests):
-        plen = int(rng.integers(4, 24))
-        eng.submit(Request(
-            uid=i, prompt=rng.integers(0, cfg.vocab_size, plen, dtype=np.int32),
-            max_new_tokens=args.max_new, temperature=args.temperature,
-            top_k=args.top_k, top_p=args.top_p, decoding=args.decoding))
+    for req in make_requests(args, cfg.vocab_size):
+        eng.submit(req)
     t0 = time.time()
     chunks = eng.run_to_completion()
     dt = time.time() - t0

@@ -9,12 +9,14 @@ VLM gets patch embeddings, audio gets frame embeddings (see DESIGN.md §5).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ArchConfig, ShapeSpec
+from repro.core.quantize import QuantizedWeight
 from repro.models import audio, hybrid, moe, ssm, transformer, vlm
 from repro.models import kvcache, layers as L, quantized
 from repro.distributed.sharding import constrain_tree, shard
@@ -93,13 +95,58 @@ class _SsmLM:
 # unified entry points
 # ---------------------------------------------------------------------------
 
+# float bytes one serving-init program may generate: a larger leaf gets a
+# program of its own, smaller ones share one up to this size
+_INIT_GROUP_BYTES = 1 << 30
+
+
 def init_params(key, cfg: ArchConfig, *, serve_quantized: bool = False):
     """Float params; with serve_quantized=True, projections become packed
-    low-bit QuantizedWeights per cfg.quant (the paper's serving format)."""
-    params = get_module(cfg.family).init(key, cfg)
-    if serve_quantized and cfg.quant:
-        params = quantized.quantize_params(params, cfg.quant)
-    return params
+    low-bit QuantizedWeights per cfg.quant (the paper's serving format).
+
+    The serving tree is ``quantize_params(init(key))``, built a few output
+    nodes (float leaves or QuantizedWeights) per jitted program: XLA drops
+    the generation of every other leaf, so a program holds at most one
+    large float leaf (or up to 1 GiB of small ones) and its quantizer
+    temporaries. (The float tree of paper-bitnet-3b is 13.7 GB in float32;
+    a v5e chip has 16 GiB.)
+    """
+    module = get_module(cfg.family)
+    if not (serve_quantized and cfg.quant):
+        return module.init(key, cfg)
+
+    def full(k):
+        return quantized.quantize_params(module.init(k, cfg), cfg.quant)
+
+    def nodes(tree):
+        return jax.tree_util.tree_flatten(tree, is_leaf=_is_qw)
+
+    specs, treedef = nodes(jax.eval_shape(full, key))
+    groups, size = [[]], 0
+    for i, spec in enumerate(specs):
+        b = _float_bytes(spec)
+        if groups[-1] and size + b > _INIT_GROUP_BYTES:
+            groups.append([])
+            size = 0
+        groups[-1].append(i)
+        size += b
+    out = []
+    for idx in groups:
+        out += jax.jit(lambda k, idx=tuple(idx): [
+            nodes(full(k))[0][i] for i in idx])(key)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _is_qw(x):
+    return isinstance(x, QuantizedWeight)
+
+
+def _float_bytes(spec) -> int:
+    """float32 bytes the init generates for one output node."""
+    if _is_qw(spec):
+        lead = spec.scale.shape[:-1]
+        return math.prod(lead) * spec.n * spec.k_total * 4
+    return math.prod(spec.shape) * 4
 
 
 def forward(params, batch, cfg: ArchConfig, **kw):

@@ -25,7 +25,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import mpgemm as mp
 from repro.core.quantize import fake_quant
-from repro.distributed._compat import shard_map
 from repro.distributed.sharding import current_plan
 from repro.models import kvcache
 
@@ -355,7 +354,7 @@ def flash_decode_shardmap(q, cache, pos, plan, *, chunk=1024):
         return out.reshape(q_.shape).astype(q_.dtype)
 
     in_specs = (qspec, P()) + (cspec,) * len(cache)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                    out_specs=qspec, check_vma=False)
     return fn(q, jnp.asarray(pos), *cache)
 

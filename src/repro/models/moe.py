@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantize import fake_quant
-from repro.distributed._compat import shard_map
 from repro.distributed.sharding import constrain_tree, shard
 from repro.models import kvcache, layers as L
 from repro.models import transformer as TR
@@ -190,7 +189,7 @@ def _moe_mlp_shardmap(p: Params, x: jax.Array, cfg, quant, plan):
     shared = p.get("shared_mlp")
     shared_spec = None if shared is None else jax.tree.map(
         lambda _: P(), shared)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(), espec, espec, dspec, shared_spec),
         out_specs=(xspec, P(), P()),

@@ -33,14 +33,6 @@ from typing import Any, Dict, List, Optional
 __all__ = ["Tracer", "validate_chrome_trace", "load_trace"]
 
 
-def _trace_annotation_cls():
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation
-    except Exception:  # jax absent or profiler API moved
-        return None
-
-
 class Tracer:
     """Append-only span/event recorder emitting Chrome-trace JSON.
 
@@ -65,7 +57,10 @@ class Tracer:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._t0 = time.perf_counter_ns()
-        self._ann_cls = _trace_annotation_cls() if annotate_xla else None
+        self._ann_cls = None
+        if annotate_xla:
+            from jax.profiler import TraceAnnotation
+            self._ann_cls = TraceAnnotation
         if enabled:
             self._meta("process_name", {"name": process_name})
 

@@ -172,6 +172,7 @@ class ServingEngine:
         # mesh plan, where every constraint resolves to replication.
         self.plan = plan
         if plan is not None:
+            params = shrules.pad_row_parallel(params, plan)
             params = jax.device_put(
                 params, shrules.named_sharding_tree(params, plan))
         elif (cfg.quant and jax.default_backend() == "cpu"

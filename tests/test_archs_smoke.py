@@ -117,3 +117,50 @@ def test_serve_quantized_params(arch):
 def test_assigned_arch_count():
     assert len(registry.ASSIGNED) == 10
     assert len(ARCHS) == 11  # + paper-bitnet-3b
+
+
+@pytest.mark.parametrize("arch", ["paper-bitnet-3b", "olmoe-1b-7b",
+                                  "zamba2-7b"])
+def test_serving_init_matches_whole_tree_quantize(arch):
+    """The serving init, built a few output leaves per program (what fits
+    a 3B model on one chip), gives exactly the tree that quantizing the
+    whole float tree in one program gives: same structure, every leaf
+    bit-identical."""
+    from repro.models import quantized
+    cfg = registry.get_reduced(arch)
+    key = jax.random.key(3)
+    want = jax.jit(lambda k: quantized.quantize_params(
+        api.get_module(cfg.family).init(k, cfg), cfg.quant))(key)
+    got = api.init_params(key, cfg, serve_quantized=True)
+    wl, wt = jax.tree_util.tree_flatten(want)
+    gl, gt = jax.tree_util.tree_flatten(got)
+    assert gt == wt
+    for w, g in zip(wl, gl):
+        assert w.dtype == g.dtype
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+
+
+@pytest.mark.parametrize("arch", ["paper-bitnet-3b", "olmoe-1b-7b",
+                                  "zamba2-7b"])
+def test_serving_init_matches_eager_quantize(arch):
+    """Against the float tree quantized op by op (the serving init before
+    it was split into programs): every packed code and other integer leaf
+    bit-identical; float leaves within 4 ulp, since XLA fuses the init's
+    scaling and the per-channel weight scale's reduction differently in
+    one program than op by op (at most 3 ulp seen)."""
+    from repro.models import quantized
+    cfg = registry.get_reduced(arch)
+    key = jax.random.key(3)
+    want = quantized.quantize_params(
+        api.get_module(cfg.family).init(key, cfg), cfg.quant)
+    got = api.init_params(key, cfg, serve_quantized=True)
+    wl, wt = jax.tree_util.tree_flatten(want)
+    gl, gt = jax.tree_util.tree_flatten(got)
+    assert gt == wt
+    for w, g in zip(wl, gl):
+        w, g = np.asarray(w), np.asarray(g)
+        assert w.dtype == g.dtype
+        if np.issubdtype(w.dtype, np.floating):
+            np.testing.assert_array_max_ulp(w, g, maxulp=4)
+        else:
+            np.testing.assert_array_equal(w, g)

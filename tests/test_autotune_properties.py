@@ -1,9 +1,9 @@
 """Property-based tests (hypothesis) for the autotune dispatch layer:
 
   * block pickers (``ops.pick_blocks`` / ``ops._clamp_blocks``) always emit
-    kernel-valid blocks — positive, packed-stream byte-aligned, within the
-    LMMA VMEM budget — for adversarial shapes including odd group counts
-    and non-power-of-two k_group;
+    kernel-valid blocks — the TPU (sublane, lane) tiling rule on every
+    kernel operand's block, within the LMMA VMEM budget — for adversarial
+    shapes including odd group counts and non-power-of-two k_group;
   * tuned configs loaded from a foreign/adversarial cache are always either
     rejected or sanitized into valid candidates — ``fusion="tuned"``
     dispatch can never crash because of a cache file.
@@ -22,6 +22,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import autotune, lmma
 from repro.core.autotune import TunedConfig
+from repro.core.packing import chunk_groups
 from repro.kernels import ops
 
 settings.register_profile("ci", max_examples=25, deadline=None)
@@ -35,22 +36,54 @@ kg_st = st.sampled_from([1, 2, 3, 4, 5, 8])
 planes_st = st.integers(1, 4)
 
 
-def _assert_valid_blocks(bm, bn, bg, k_group, planes):
+def _round_up(x, mult):
+    return -(-x // mult) * mult
+
+
+_SUBLANES = {4: 8, 2: 16, 1: 32}   # TPU tile rows by itemsize; lanes: 128
+
+
+def _tile_ok(block, array, itemsize):
+    """Mosaic's rule on a block's last two dims: a multiple of the
+    (sublane, lane) tile of its dtype, or the whole array dim."""
+    (br, bc), (ar, ac) = block, array
+    return ((br % _SUBLANES[itemsize] == 0 or br == ar)
+            and (bc % 128 == 0 or bc == ac))
+
+
+def _assert_valid_blocks(m, n, g, bm, bn, bg, k_group, planes):
+    """Every kernel operand's block obeys the TPU tiling rule."""
     assert isinstance(bm, int) and isinstance(bn, int) and isinstance(bg, int)
     assert bm >= 1 and bn >= 1 and bg >= 1
-    # packed-stream byte alignment: every wrapper requires it
-    assert (bg * planes * k_group) % 8 == 0
+    # packed-stream alignment: a K-block is whole 128-byte lane columns
+    assert (bg * planes * k_group) % (8 * 128) == 0 and bg % 128 == 0
+    e = 1 << (k_group - 1)
+    mp, gt = _round_up(m, bm), _round_up(g, bg)
+    gs = _round_up(g, chunk_groups(k_group, planes))  # stored groups
+    operands = [
+        ("activations f32", (bm, bg * k_group), (mp, gt * k_group), 4),
+        ("activations bf16", (bm, bg * k_group), (mp, gt * k_group), 2),
+        ("table int8", (bm, bg * e), (mp, gt * e), 1),
+        ("table f32", (bm, bg * e), (mp, gt * e), 4),
+        ("row scale", (bm, 1), (mp, 1), 4),
+        ("group scale", (bm, bg), (mp, gt), 4),
+        ("packed uint8", (bn, bg * planes * k_group // 8),
+         (n, gs * planes * k_group // 8), 1),
+        ("weight scale", (1, bn), (1, n), 4),
+        ("output", (bm, bn), (mp, n), 4),
+    ]
+    for name, block, array, itemsize in operands:
+        assert _tile_ok(block, array, itemsize), (name, block, array)
 
 
 @given(m=m_st, n=n_st, g=g_st, kg=kg_st, planes=planes_st)
 def test_pick_blocks_always_valid(m, n, g, kg, planes):
-    """Scheduler-chosen blocks: positive, byte-aligned, VMEM-feasible."""
+    """Scheduler-chosen blocks: tile-aligned on every operand, VMEM-feasible."""
     bm, bn, bg = ops.pick_blocks(m, n, g, kg, planes)
-    _assert_valid_blocks(bm, bn, bg, kg, planes)
+    _assert_valid_blocks(m, n, g, bm, bn, bg, kg, planes)
     desc = lmma.LMMADescriptor(m=m, n=n, k=g * kg, w_bits=planes, k_group=kg)
-    t, w, a = lmma._tile_bytes(min(bm, max(8, m)), min(bn, n),
-                               min(bg, g), desc)
-    assert 2 * (t + w) + a <= lmma.VMEM_BYTES
+    t, w, a = lmma._tile_bytes(bm, bn, bg, desc)
+    assert 2 * (t + w) + a <= lmma.TILE_BUDGET
 
 
 @given(m=m_st, n=n_st, g=g_st, kg=kg_st, planes=planes_st,
@@ -63,9 +96,11 @@ def test_clamp_blocks_always_valid(m, n, g, kg, planes,
     auto_fusion resolves them to a real mode without crashing."""
     bm, bn, bg = ops._clamp_blocks(m, n, g, kg, planes,
                                    block_m, block_n, block_g)
-    _assert_valid_blocks(bm, bn, bg, kg, planes)
+    _assert_valid_blocks(m, n, g, bm, bn, bg, kg, planes)
     if block_m is not None:
-        assert bm == block_m  # pinned knobs always win
+        # pinned knobs win, rounded up to the int8 tile (never shrunk
+        # below it) and clamped to the padded problem
+        assert bm == min(_round_up(block_m, 32), _round_up(m, 32))
     assert ops.auto_fusion(m, n, g, kg, planes, bm, bn, bg) in \
         ("fused", "staged")
 
@@ -88,13 +123,14 @@ def test_sanitize_foreign_entry_never_invalid(m, n, g, kg, planes,
     if out is None:
         return
     assert out.fusion in ("fused", "staged")
-    _assert_valid_blocks(out.block_m, out.block_n, out.block_g, kg, planes)
-    assert out.block_m <= max(8, m) and out.block_n <= max(1, n)
+    _assert_valid_blocks(m, n, g, out.block_m, out.block_n, out.block_g,
+                         kg, planes)
+    assert out.block_m <= _round_up(m, 32) and out.block_n <= _round_up(n, 128)
     if out.fusion == "fused":
         desc = lmma.LMMADescriptor(m=m, n=n, k=g * kg, w_bits=planes,
                                    k_group=kg)
         assert lmma.fused_tile_bytes(out.block_m, out.block_n, out.block_g,
-                                     desc) <= lmma.VMEM_BYTES
+                                     desc) <= lmma.TILE_BUDGET
 
 
 @given(m=st.integers(1, 64), n=st.integers(1, 1024), g=st.integers(1, 256),
@@ -112,6 +148,6 @@ def test_tuned_dispatch_never_crashes_on_bad_cache(m, n, g, kg, planes,
         rf, rbm, rbn, rbg = ops.resolve_dispatch(m, n, g, kg, planes,
                                                  fusion="tuned")
         assert rf in ("fused", "staged")
-        _assert_valid_blocks(rbm, rbn, rbg, kg, planes)
+        _assert_valid_blocks(m, n, g, rbm, rbn, rbg, kg, planes)
     finally:
         autotune.deactivate()

@@ -92,7 +92,12 @@ def test_pack_unpack_roundtrip(bits, kg, n, g, seed):
     idx = jnp.asarray(rng.integers(0, 1 << (kg - 1), size=(n, g, bits)),
                       jnp.uint8)
     packed = packing.pack_group_codes(sign, idx, kg)
-    assert packed.shape[1] == (g * bits * kg + 7) // 8  # true low-bit storage
+    # true low-bit storage up to one packing chunk of padding, in whole
+    # 128-byte lane columns
+    assert packed.shape[1] == packing.packed_bytes_per_channel(g * kg, bits, kg)
+    assert packed.shape[1] % packing.LANES == 0
+    chunk = packing.chunk_groups(kg, bits) * bits * kg // 8
+    assert packed.shape[1] < (g * bits * kg + 7) // 8 + chunk
     s2, i2 = packing.unpack_group_codes(packed, kg, g, bits)
     np.testing.assert_array_equal(np.asarray(sign), np.asarray(s2))
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(i2))

@@ -68,7 +68,8 @@ def test_divisibility_fallback_replicates():
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import AxisPlan, named_sharding_tree
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = AxisPlan(mesh=mesh, batch=("data",), fsdp="data")
     params = {"attn": {"wq": {"w": jnp.zeros((6, 10))}}}  # 10 % 4 != 0
     sh = named_sharding_tree(params, plan)
@@ -95,7 +96,8 @@ def test_pjit_train_step_8dev():
 
     cfg = registry.get_reduced("tinyllama-1.1b").replace(
         activation_dtype=jnp.float32)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = AxisPlan(mesh=mesh, batch=("data",), fsdp="data")
     opt = O.make_optimizer("adamw", lr=3e-3)
     state = init_train_state(jax.random.key(0), cfg, opt)
@@ -136,7 +138,8 @@ def test_sharded_quantized_decode_8dev():
     from repro.models import api
 
     cfg = registry.get_reduced("qwen2-72b").replace(activation_dtype=jnp.float32)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = AxisPlan(mesh=mesh, batch=("data",), fsdp=None)
     params = api.init_params(jax.random.key(0), cfg, serve_quantized=True)
     sh = named_sharding_tree(params, plan)
@@ -162,8 +165,9 @@ def test_pipeline_parallel_4stage():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipelined_forward, split_stages
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4,), ("pp",))
+    mesh = make_mesh((4,), ("pp",))
     L, D = 8, 16
     key = jax.random.key(0)
     ws = jax.random.normal(key, (L, D, D)) * (D ** -0.5)

@@ -68,8 +68,9 @@ def test_cw_format_exact_vs_packed():
 
 def test_cw_bytes_accounting():
     """CW store at W2/K=2 is exactly 1 byte/weight (4x packed, 2x smaller
-    than bf16)."""
-    w = jnp.asarray(np.random.default_rng(1).normal(size=(128, 256)),
+    than bf16). K=512 is one whole packing chunk (256 groups), where packed
+    storage carries no padding."""
+    w = jnp.asarray(np.random.default_rng(1).normal(size=(128, 512)),
                     jnp.float32)
     qw = Q.quantize(w, 2, k_group=2)
     qcw = Q.to_cw_format(qw)
@@ -87,7 +88,8 @@ def test_flash_decode_matches_chunked_8dev():
 
     cfg = registry.get_reduced("qwen2-72b").replace(activation_dtype=jnp.float32)
     params = api.init_params(jax.random.key(0), cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = AxisPlan(mesh=mesh, batch=("data",), fsdp=None)
     b, s_cache = 4, 32  # 32 % 4 == 0 -> flash path eligible
     toks = jnp.asarray(np.random.default_rng(0).integers(
@@ -124,7 +126,8 @@ def test_moe_shardmap_matches_global_8dev():
     cfg = registry.get_reduced("olmoe-1b-7b").replace(
         activation_dtype=jnp.float32, capacity_factor=64.0)
     params = api.init_params(jax.random.key(0), cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     plan = AxisPlan(mesh=mesh, batch=("data",), fsdp="data")
     x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 8, cfg.d_model)),
                     jnp.float32) * 0.3
