@@ -5,7 +5,7 @@ PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
 .PHONY: install test test-fast bench-smoke bench-serving bench-autotune \
-	bench-distributed bench-decoding bench-telemetry
+	bench-distributed bench-decoding
 
 install:
 	$(PYTHON) -m pip install -r requirements.txt
@@ -38,7 +38,3 @@ bench-distributed: ## tensor-parallel sharded decode vs dense -> JSON
 bench-decoding:  ## beam + bit-plane self-speculation vs greedy -> JSON
 	$(PYTHON) benchmarks/bench_decoding.py --reduced \
 		--assert-spec-speedup 1.0 --out BENCH_decoding.json
-
-bench-telemetry: ## telemetry overhead gate (tracing-on >= 0.97x off) -> JSON
-	$(PYTHON) benchmarks/bench_telemetry.py --assert-overhead 0.97 \
-		--out BENCH_telemetry.json

@@ -60,12 +60,15 @@ def resolve_table_quant(table_quant: Optional[str]) -> Optional[str]:
 
 
 def precompute_tables(x, k_group: int = 4, table_quant: Optional[str] = "per_row") -> Table:
-    """Independent table-precompute operator (fuse me with your previous op)."""
+    """Independent table-precompute operator (fuse me with your previous op).
+
+    Traced under ``mpgemm/table``: a table shared by several consumers is
+    still mpGEMM work."""
     table_quant = resolve_table_quant(table_quant)
-    lead = x.shape[:-1]
-    t = precompute_table(x.reshape(-1, x.shape[-1]), k_group, table_quant)
-    del lead  # table stays flat [M, G, E]; mpgemm reshapes the output
-    return t
+    # the table stays flat [M, G, E]; mpgemm reshapes the output
+    with jax.named_scope("mpgemm"), jax.named_scope("table"):
+        return precompute_table(x.reshape(-1, x.shape[-1]), k_group,
+                                table_quant)
 
 
 def _lut_xla(x2d, qw: QuantizedWeight, table_quant, table: Optional[Table]):
@@ -101,6 +104,10 @@ def mpgemm(
     scheduler decide from the VMEM budget, "tuned" uses the persistent
     measured-time autotune cache (auto on a miss). Ignored when ``table=``
     is supplied — a shared table is by definition staged.
+
+    Every mode traces under the named scope ``mpgemm`` (sub-scopes ``table``
+    and ``cw`` for the table precompute and the CW build), so the compiled
+    program's op metadata says which ops are mpGEMM work.
     """
     if mode not in MPGEMM_MODES:
         raise ValueError(f"mode {mode!r} not in {MPGEMM_MODES}")
@@ -109,21 +116,21 @@ def mpgemm(
         raise ValueError(f"contract dim {x.shape[-1]} != k_total {qw.k_total}")
     out_dtype = out_dtype or x.dtype
     lead = x.shape[:-1]
-    x2d = x.reshape(-1, qw.k_total)
-
-    if mode == "fp16":
-        w = dequantize(qw).astype(x.dtype)
-        out = jnp.dot(x2d, w.T, preferred_element_type=jnp.float32)
-    elif mode == "dequant":
-        # Unpack + upcast happen *inside* the jitted graph: HLO parameter
-        # bytes stay truly low-bit; the upcast is the baseline's cost.
-        w = dequantize(qw).astype(jnp.bfloat16)
-        out = jnp.dot(x2d.astype(jnp.bfloat16), w.T,
-                      preferred_element_type=jnp.float32)
-    elif mode == "lut_xla":
-        out = _lut_xla(x2d, qw, table_quant, table)
-    else:  # lut_pallas
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        out = _lut_pallas(x2d, qw, table_quant, table, fusion, interpret)
-    return out.reshape(*lead, qw.n).astype(out_dtype)
+    with jax.named_scope("mpgemm"):
+        x2d = x.reshape(-1, qw.k_total)
+        if mode == "fp16":
+            w = dequantize(qw).astype(x.dtype)
+            out = jnp.dot(x2d, w.T, preferred_element_type=jnp.float32)
+        elif mode == "dequant":
+            # Unpack + upcast happen *inside* the jitted graph: HLO parameter
+            # bytes stay truly low-bit; the upcast is the baseline's cost.
+            w = dequantize(qw).astype(jnp.bfloat16)
+            out = jnp.dot(x2d.astype(jnp.bfloat16), w.T,
+                          preferred_element_type=jnp.float32)
+        elif mode == "lut_xla":
+            out = _lut_xla(x2d, qw, table_quant, table)
+        else:  # lut_pallas
+            if interpret is None:
+                interpret = jax.default_backend() != "tpu"
+            out = _lut_pallas(x2d, qw, table_quant, table, fusion, interpret)
+        return out.reshape(*lead, qw.n).astype(out_dtype)

@@ -308,8 +308,10 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
             x, qw, table_quant=table_quant, block_m=bm, block_n=bn,
             block_g=bg, interpret=interpret)
     if table is None:
-        tv, ts = _precompute(x, qw.k_group, table_quant, bm, bg, interpret)
-        rowsum = jnp.sum(x.astype(jnp.float32), axis=-1)
+        with jax.named_scope("table"):
+            tv, ts = _precompute(x, qw.k_group, table_quant, bm, bg,
+                                 interpret)
+            rowsum = jnp.sum(x.astype(jnp.float32), axis=-1)
     else:
         gt = round_up(g, bg)
         tv = _pad_to(_table_to_kernel_layout(table.values, gt), bm, 0)

@@ -89,20 +89,21 @@ def build_cw(qw: QuantizedWeight, dtype=jnp.int8):
     intermediate would be 4-16x the size of CW itself (12.8 GiB for
     qwen2-72b's LM head).
     """
-    if qw.cw is not None:
-        return qw.cw.astype(dtype)
-    sign, idx = qw.sign_idx()  # [N, G, B]
-    e = 1 << (qw.k_group - 1)
-    ent = jnp.arange(e, dtype=idx.dtype)
-    cw = None
-    for b, ps in enumerate(qw.plane_scales):
-        coef = (int(ps) * (1 - 2 * sign[..., b].astype(jnp.int8))).astype(
-            jnp.int8)
-        term = jnp.where(idx[..., b, None] == ent, coef[..., None],
-                         jnp.int8(0))  # [N, G, E]
-        cw = term if cw is None else cw + term
-    n, g = qw.n, qw.g
-    return jnp.transpose(cw, (1, 2, 0)).reshape(g * e, n).astype(dtype)
+    with jax.named_scope("cw"):
+        if qw.cw is not None:
+            return qw.cw.astype(dtype)
+        sign, idx = qw.sign_idx()  # [N, G, B]
+        e = 1 << (qw.k_group - 1)
+        ent = jnp.arange(e, dtype=idx.dtype)
+        cw = None
+        for b, ps in enumerate(qw.plane_scales):
+            coef = (int(ps) * (1 - 2 * sign[..., b].astype(jnp.int8))).astype(
+                jnp.int8)
+            term = jnp.where(idx[..., b, None] == ent, coef[..., None],
+                             jnp.int8(0))  # [N, G, E]
+            cw = term if cw is None else cw + term
+        n, g = qw.n, qw.g
+        return jnp.transpose(cw, (1, 2, 0)).reshape(g * e, n).astype(dtype)
 
 
 def ref_lut_mpgemm_matmul(a, qw: QuantizedWeight,
@@ -110,7 +111,10 @@ def ref_lut_mpgemm_matmul(a, qw: QuantizedWeight,
                           table: Optional[Table] = None,
                           out_dtype=jnp.float32):
     """T @ CW single-GEMM formulation (accepts a precomputed/fused table)."""
-    t = table if table is not None else precompute_table(a, qw.k_group, table_quant)
+    t = table
+    if t is None:
+        with jax.named_scope("table"):
+            t = precompute_table(a, qw.k_group, table_quant)
     m = a.shape[0]
     e = 1 << (qw.k_group - 1)
     if t.scale is None:

@@ -397,9 +397,6 @@ def attention_apply(
     """
     b, s, d = x.shape
     hd = cfg.head_dim
-    # per-slot decode (continuous batching): cache_pos is a [B] vector and
-    # s == 1; each slot reads/writes its own position.
-    per_slot = getattr(jnp.asarray(cache_pos), "ndim", 0) == 1
     tbl = make_table(x, quant)
     q = lut_dense(p["wq"], x, quant, tbl).reshape(b, s, cfg.n_heads, hd)
     if xattn_kv is None:
@@ -407,6 +404,26 @@ def attention_apply(
         v = lut_dense(p["wv"], x, quant, tbl).reshape(b, s, cfg.n_kv_heads, hd)
     else:
         k, v = xattn_kv  # precomputed cross-attention KV (encoder/image)
+    # the "attention" scope holds everything between the projections (RoPE,
+    # the cache write, scores, softmax, values); the projections are mpGEMMs
+    with jax.named_scope("attention"):
+        out, new_cache = _attend(
+            q, k, v, cfg, kv_cache=kv_cache, cache_pos=cache_pos,
+            cross=xattn_kv is not None, positions=positions, window=window,
+            causal=causal, use_rope=use_rope, page_table=page_table)
+    return lut_dense(p["wo"], out, quant), new_cache
+
+
+def _attend(q, k, v, cfg, *, kv_cache, cache_pos, cross, positions, window,
+            causal, use_rope, page_table):
+    """Attention of ``q`` over the fresh ``k``/``v`` and the cache: returns
+    (out [B, S, H*hd] before the output projection, new cache)."""
+    b, s = q.shape[:2]
+    hd = cfg.head_dim
+    # per-slot decode (continuous batching): cache_pos is a [B] vector and
+    # s == 1; each slot reads/writes its own position.
+    per_slot = getattr(jnp.asarray(cache_pos), "ndim", 0) == 1
+    xattn_kv = (k, v) if cross else None
 
     if positions is None:
         if per_slot:
@@ -464,7 +481,7 @@ def attention_apply(
                 q_offset=cp, causal=causal, kv_valid_len=cp + s,
                 chunk=getattr(cfg, "attn_chunk", 1024))
         out = out.reshape(b, s, cfg.n_heads * hd)
-        return lut_dense(p["wo"], out, quant), new_cache
+        return out, new_cache
 
     if per_slot and kv_cache is not None and xattn_kv is None:
         bi = jnp.arange(b)
@@ -496,7 +513,7 @@ def attention_apply(
                 q_offset=0, causal=False, kv_valid_len=vlen,
                 chunk=getattr(cfg, "attn_chunk", 1024))
             out = out.reshape(b, s, cfg.n_heads * hd)
-            return lut_dense(p["wo"], out, quant), (ck, cv, cks, cvs)
+            return out, (ck, cv, cks, cvs)
         ck, cv = kv_cache
         if s == 1:
             ck = ck.at[bi, cp].set(k[:, 0].astype(ck.dtype))
@@ -510,7 +527,7 @@ def attention_apply(
             kv_valid_len=vlen,
             chunk=getattr(cfg, "attn_chunk", 1024))
         out = out.reshape(b, s, cfg.n_heads * hd)
-        return lut_dense(p["wo"], out, quant), (ck, cv)
+        return out, (ck, cv)
 
     q_off = jnp.asarray(cache_pos)
     plan = current_plan()
@@ -561,7 +578,7 @@ def attention_apply(
                                 window=window,
                                 chunk=getattr(cfg, "attn_chunk", 1024))
         out = out.reshape(b, s, cfg.n_heads * hd)
-        return lut_dense(p["wo"], out, quant), new_cache
+        return out, new_cache
 
     # ---- flash-decode (§Perf B4): sequence-sharded cache over the model
     # axis, local online-softmax per shard, one (m,l,acc) merge per layer.
@@ -585,7 +602,7 @@ def attention_apply(
             new_cache = (ck, cv)
         out = flash_decode_shardmap(q, new_cache, q_off, plan)
         out = out.reshape(b, s, cfg.n_heads * hd)
-        return lut_dense(p["wo"], out, quant), new_cache
+        return out, new_cache
 
     if kv_cache is not None and xattn_kv is None and len(kv_cache) == 4:
         # int8 KV cache (paper §5 direction): quantize the new slice with
@@ -608,7 +625,7 @@ def attention_apply(
             q_offset=q_off, causal=causal, kv_valid_len=q_off + s,
             window=window, chunk=getattr(cfg, "attn_chunk", 1024))
         out = out.reshape(b, s, cfg.n_heads * hd)
-        return lut_dense(p["wo"], out, quant), (ck, cv, cks, cvs)
+        return out, (ck, cv, cks, cvs)
     if kv_cache is not None and xattn_kv is None:
         ck, cv = kv_cache
         s_max = ck.shape[1]
@@ -623,7 +640,7 @@ def attention_apply(
             out = _attend_rolling(q, ck, cv, q_pos=q_off + jnp.arange(s),
                                   stored_pos=stored_pos, window=window)
             out = out.reshape(b, s, cfg.n_heads * hd)
-            return lut_dense(p["wo"], out, quant), (ck, cv)
+            return out, (ck, cv)
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), q_off, 1)
         cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), q_off, 1)
         k, v = ck, cv
@@ -639,7 +656,7 @@ def attention_apply(
         window=window, kv_valid_len=kv_valid,
         chunk=getattr(cfg, "attn_chunk", 1024))
     out = out.reshape(b, s, cfg.n_heads * hd)
-    return lut_dense(p["wo"], out, quant), kv_cache
+    return out, kv_cache
 
 
 def _rolling_positions(next_pos, window):

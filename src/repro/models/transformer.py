@@ -35,8 +35,9 @@ def embed_apply(p: Params, tokens: jax.Array) -> jax.Array:
 
 
 def head_apply(p: Params, h: jax.Array, quant=None) -> jax.Array:
-    logits = L.lut_dense(p, h, quant)
-    return shard(logits, "batch", None, "model")  # vocab-sharded logits
+    with jax.named_scope("lm_head"):  # its mpGEMM is lm_head/mpgemm
+        logits = L.lut_dense(p, h, quant)
+        return shard(logits, "batch", None, "model")  # vocab-sharded logits
 
 
 def lm_loss(logits: jax.Array, labels: jax.Array,

@@ -9,22 +9,25 @@ scope, so the kernels/core layers can hook in without cycles):
     Prometheus text exposition. The serving engine, block pool, tuning
     cache, and benches all emit through it.
   * :mod:`repro.obs.trace` — span/event tracer exporting Chrome-trace /
-    Perfetto JSON. Spans optionally wrap ``jax.profiler.TraceAnnotation``
-    so host spans line up with XLA device profiles. The overhead contract:
-    timestamps are taken only at host sync points that already exist —
-    tracing never adds a device round-trip.
+    Perfetto JSON. Spans wrap ``jax.profiler.TraceAnnotation`` so host
+    spans line up with XLA device profiles; tracing never adds a device
+    round-trip.
+  * :mod:`repro.obs.scopes` — the map from a compiled program's ops to the
+    model's named scopes (``mpgemm``, ``attention``, ``lm_head``), which
+    puts a device trace's time down to them.
   * :mod:`repro.obs.dispatch` — trace-time kernel-dispatch recorder:
     which (shape-key, fusion, blocks) actually dispatched, tuned vs
     heuristic, per jitted-program trace.
 
-See docs/OBSERVABILITY.md for the span taxonomy, metric names/units, and
-the overhead contract gated by ``benchmarks/bench_telemetry.py``.
+See docs/OBSERVABILITY.md for the span taxonomy, the scopes, metric
+names/units, and the overhead contract as measured on the chip.
 """
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                export_stats)
 from repro.obs.trace import Tracer, validate_chrome_trace
-from repro.obs import dispatch
+from repro.obs import dispatch, scopes
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "export_stats", "Tracer", "validate_chrome_trace", "dispatch"]
+           "export_stats", "Tracer", "validate_chrome_trace", "dispatch",
+           "scopes"]

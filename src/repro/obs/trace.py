@@ -1,24 +1,23 @@
 """Request-lifecycle tracer: Chrome-trace / Perfetto JSON span recording.
 
 The span taxonomy (docs/OBSERVABILITY.md) follows one request through the
-engine: ``admit`` → ``prefill_chunk``(s) → ``decode_chunk``(s) → retire,
-with the request's whole lifetime drawn as an async span keyed by uid.
+engine: arrival → ``admit`` (with its ``prefill_chunk`` dispatches) →
+``decode_chunk``s (``decode_dispatch`` then ``decode_sync``) each followed
+by ``emit`` → retire, with the request's whole lifetime, from its arrival,
+drawn as an async span keyed by uid.
 
-Overhead contract (gated by ``benchmarks/bench_telemetry.py``):
+Overhead contract:
 
-  * timestamps are host ``perf_counter_ns`` taken ONLY where the engine
-    already syncs or dispatches — tracing adds zero device round-trips and
-    must not change ``host_syncs_per_token``;
-  * recording one span is two clock reads and one list append — no
-    serialization until ``save()``;
+  * timestamps are host ``perf_counter_ns``; tracing adds zero device
+    round-trips and must not change ``host_syncs_per_token``;
+  * recording one span is two clock reads, one ``TraceAnnotation`` and one
+    list append — no serialization until ``save()``;
   * a disabled tracer (``enabled=False``) short-circuits to a no-op
-    context manager, so engine call sites need no conditionals.
+    context manager; an engine with no tracer pays one branch per site.
 
 When ``annotate_xla=True`` (default) every synchronous span also enters a
 ``jax.profiler.TraceAnnotation`` with the same name, so host spans line up
 with XLA device traces when a ``jax.profiler.trace()`` session is active.
-The import is lazy and failure-tolerant: the tracer works in environments
-where jax (or its profiler) is absent.
 """
 
 from __future__ import annotations
@@ -133,12 +132,18 @@ class Tracer:
                     "pid": self.pid, "tid": self._tid(),
                     "ts": self._us(time.perf_counter_ns()), "args": attrs})
 
-    def async_begin(self, name: str, id: int, cat: str = "request", **attrs):
+    def async_begin(self, name: str, id: int, cat: str = "request",
+                    ts_ns: Optional[int] = None, **attrs):
+        """Open an async span at ``ts_ns`` (perf_counter_ns; default now),
+        which may lie in the past: a request's span opens at its arrival
+        (at the tracer's start if it arrived before that)."""
         if not self.enabled:
             return
+        ts_ns = time.perf_counter_ns() if ts_ns is None else max(ts_ns,
+                                                                 self._t0)
         self._emit({"name": name, "ph": "b", "cat": cat, "id": int(id),
                     "pid": self.pid, "tid": self._tid(),
-                    "ts": self._us(time.perf_counter_ns()), "args": attrs})
+                    "ts": self._us(ts_ns), "args": attrs})
 
     def async_end(self, name: str, id: int, cat: str = "request", **attrs):
         if not self.enabled:

@@ -46,6 +46,7 @@ Known edges (documented, covered by tests):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -63,10 +64,14 @@ from repro.distributed import sharding as shrules
 from repro.distributed.sharding import AxisPlan, plan_scope
 from repro.models import api, kvcache
 from repro.obs import dispatch as dispatch_obs
+from repro.obs import scopes
 from repro.obs.metrics import MetricsRegistry, export_stats
 from repro.obs.trace import Tracer
 from repro.serving import blockpool, decoding
 from repro.serving.sampler import mask_logits, sample
+
+# an engine span site with no tracer enters this shared no-op context
+_NO_SPAN = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -84,6 +89,12 @@ class Request:
     # retired hypotheses as (tokens, length-normalized score), best first
     spec_stats: Optional[Dict[str, int]] = None  # spec mode: verify_steps /
     # accepted_draft_tokens for this request
+    # lifecycle on the time.perf_counter_ns() clock: arrival (set by
+    # submit() unless the caller gave it), the start of admission, and the
+    # decode sync that first returned a token
+    arrival_ns: Optional[int] = None
+    admit_ns: Optional[int] = None
+    first_token_ns: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -139,11 +150,12 @@ class ServingEngine:
                  metrics: Optional[MetricsRegistry] = None):
         self.cfg = cfg
         # ---- telemetry (repro.obs) ---------------------------------------
-        # The tracer records request-lifecycle spans with host timestamps
-        # taken ONLY at sync/dispatch points that already exist — telemetry
-        # adds zero device round-trips (host_syncs_per_token is invariant;
-        # benchmarks/bench_telemetry.py gates the tok/s overhead). A None
-        # tracer costs one `is not None` check per site. The metrics
+        # The tracer records live spans around admission, prefill dispatch,
+        # the decode dispatch and sync, and token emission; each enters a
+        # jax.profiler.TraceAnnotation of the same name, so a profile shows
+        # them beside the device's ops. Tracing adds zero device
+        # round-trips (host_syncs_per_token is invariant). A None tracer
+        # costs one `is not None` check per site. The metrics
         # registry always exists: its bounded-reservoir histograms ARE the
         # engine's latency/occupancy storage (O(reservoir) however long the
         # engine lives, unlike the unbounded lists they replaced).
@@ -161,9 +173,6 @@ class ServingEngine:
         self._h_occupancy = self.metrics.histogram(
             "engine_slot_occupancy_ratio",
             help="occupied slots / max_batch, sampled once per chunk")
-        self._h_prefill_s = self.metrics.histogram(
-            "engine_prefill_chunk_seconds",
-            help="wall seconds per prefill-chunk dispatch", unit="s")
         # Tensor/data-parallel serving: ``plan`` shards the packed weights
         # (named_sharding_tree), the engine state and the cache pool across
         # the plan's mesh, and every jitted program traces inside
@@ -388,7 +397,6 @@ class ServingEngine:
         # memory stays O(reservoir) however long the engine serves
         self._h_chunk_s.reset()
         self._h_occupancy.reset()
-        self._h_prefill_s.reset()
         self.prefill_s = 0.0        # wall seconds spent in prefill dispatch
         self.prefill_tokens = 0     # prompt tokens actually prefilled
         self.prefill_tokens_reused = 0  # prompt tokens served from shared
@@ -770,6 +778,8 @@ class ServingEngine:
                 f"beam width {dm.beam_width} exceeds max_batch "
                 f"{self.max_batch}: the W hypotheses are W pool slots")
         req.output = []
+        if req.arrival_ns is None:
+            req.arrival_ns = time.perf_counter_ns()
         self.queue.append(req)
 
     def _truncate(self, req: Request) -> np.ndarray:
@@ -862,38 +872,31 @@ class ServingEngine:
     def _admit_one(self, i: int, req: Request):
         prompt = self._truncate(req)
         plen = int(prompt.size)
-
-        # chunked prefill of prompt[:-1] into a zeroed batch-1 slot view;
-        # the last token is fed to the first decode step instead
-        c = self.prefill_chunk
-        slot_caches = self._zero_slot
-        t0 = time.perf_counter_ns()
-        tc = t0
-        for j in range(0, plen - 1, c):
-            vl = min(c, plen - 1 - j)
-            buf = np.zeros((1, c), np.int32)
-            buf[0, :vl] = prompt[j:j + vl]
-            slot_caches = self._prefill(
-                self.params, slot_caches, jnp.asarray(buf),
-                np.int32(j), np.int32(vl))
-            tn = time.perf_counter_ns()
-            self._h_prefill_s.observe((tn - tc) / 1e9)
-            if self.tracer is not None:
-                self.tracer.complete("prefill_chunk", tc, tn, cat="prefill",
-                                     uid=req.uid, slot=i, offset=j, valid=vl)
-            tc = tn
-            self.prefill_dispatches += 1
-            self.prefill_tokens += vl
-        t1 = time.perf_counter_ns()
-        self.prefill_s += (t1 - t0) / 1e9
-        if self.tracer is not None:
-            self.tracer.complete("admit", t0, t1, uid=req.uid, slot=i,
-                                 prompt_len=plen, mode=req.decoding,
-                                 paged=False)
-
-        self._set_slot(i, req, prompt,
-                       self._merge(self.state.caches, slot_caches,
-                                   np.int32(i)))
+        tr = self.tracer
+        req.admit_ns = t0 = time.perf_counter_ns()
+        with (tr.span("admit", uid=req.uid, slot=i, prompt_len=plen,
+                      mode=req.decoding, paged=False)
+              if tr is not None else _NO_SPAN):
+            # chunked prefill of prompt[:-1] into a zeroed batch-1 slot view;
+            # the last token is fed to the first decode step instead
+            c = self.prefill_chunk
+            slot_caches = self._zero_slot
+            for j in range(0, plen - 1, c):
+                vl = min(c, plen - 1 - j)
+                buf = np.zeros((1, c), np.int32)
+                buf[0, :vl] = prompt[j:j + vl]
+                with (tr.span("prefill_chunk", cat="prefill", uid=req.uid,
+                              slot=i, offset=j, valid=vl)
+                      if tr is not None else _NO_SPAN):
+                    slot_caches = self._prefill(
+                        self.params, slot_caches, jnp.asarray(buf),
+                        np.int32(j), np.int32(vl))
+                self.prefill_dispatches += 1
+                self.prefill_tokens += vl
+            self.prefill_s += (time.perf_counter_ns() - t0) / 1e9
+            self._set_slot(i, req, prompt,
+                           self._merge(self.state.caches, slot_caches,
+                                       np.int32(i)))
 
     def _admit_one_paged(self, i: int, req: Request) -> bool:
         """Paged admission: reserve blocks, reuse shared-prefix blocks,
@@ -976,56 +979,50 @@ class ServingEngine:
 
         # prefill the unshared suffix straight into the pool (prefix hits
         # skip whole chunks; a full COW hit skips prefill entirely)
-        t0 = time.perf_counter_ns()
-        tc = t0
-        if start >= plen - 1 and self._all_pooled:
-            # everything came from shared blocks and there is no slot-
-            # resident state to reset: the fan-out fast path is pure
-            # bookkeeping, zero device work
-            new_caches = caches
-        else:
-            page_row = jnp.asarray(row_arr)[None, :]
-            # fresh zero views for the unpooled leaves each admit: the
-            # previous admit's views were donated (invalidated) by the
-            # prefill jit
-            view = jax.tree.map(
-                lambda c, z, pooled: c if pooled
-                else jnp.zeros(z.shape, z.dtype),
-                caches, self._zero_slot, self._pooled)
-            c = self.prefill_chunk
-            for j in range(start, plen - 1, c):
-                vl = min(c, plen - 1 - j)
-                buf = np.zeros((1, c), np.int32)
-                buf[0, :vl] = prompt[j:j + vl]
-                view = self._prefill_paged(self.params, view,
-                                           jnp.asarray(buf), np.int32(j),
-                                           np.int32(vl), page_row)
-                tn = time.perf_counter_ns()
-                self._h_prefill_s.observe((tn - tc) / 1e9)
-                if self.tracer is not None:
-                    self.tracer.complete("prefill_chunk", tc, tn,
-                                         cat="prefill", uid=req.uid, slot=i,
-                                         offset=j, valid=vl)
-                tc = tn
-                self.prefill_dispatches += 1
-                self.prefill_tokens += vl
-            # merge eagerly in python: pooled leaves pass through BY
-            # REFERENCE (the pool was updated in place via donation);
-            # unpooled leaves are written into slot i of the dense half
-            new_caches = jax.tree.map(
-                lambda cc, v, bax, pooled: v if pooled else
-                jax.lax.dynamic_update_slice_in_dim(
-                    cc, v.astype(cc.dtype), i, axis=bax),
-                caches, view, self._axes, self._pooled)
-        t1 = time.perf_counter_ns()
-        self.prefill_s += (t1 - t0) / 1e9
-        if self.tracer is not None:
-            self.tracer.complete("admit", t0, t1, uid=req.uid, slot=i,
-                                 prompt_len=plen, mode=req.decoding,
-                                 paged=True, shared_blocks=m0,
-                                 cow=cow_src is not None)
-
-        live = self._set_slot(i, req, prompt, new_caches, page_table=new_pt)
+        tr = self.tracer
+        req.admit_ns = t0 = time.perf_counter_ns()
+        with (tr.span("admit", uid=req.uid, slot=i, prompt_len=plen,
+                      mode=req.decoding, paged=True, shared_blocks=m0,
+                      cow=cow_src is not None)
+              if tr is not None else _NO_SPAN):
+            if start >= plen - 1 and self._all_pooled:
+                # everything came from shared blocks and there is no slot-
+                # resident state to reset: the fan-out fast path is pure
+                # bookkeeping, zero device work
+                new_caches = caches
+            else:
+                page_row = jnp.asarray(row_arr)[None, :]
+                # fresh zero views for the unpooled leaves each admit: the
+                # previous admit's views were donated (invalidated) by the
+                # prefill jit
+                view = jax.tree.map(
+                    lambda c, z, pooled: c if pooled
+                    else jnp.zeros(z.shape, z.dtype),
+                    caches, self._zero_slot, self._pooled)
+                c = self.prefill_chunk
+                for j in range(start, plen - 1, c):
+                    vl = min(c, plen - 1 - j)
+                    buf = np.zeros((1, c), np.int32)
+                    buf[0, :vl] = prompt[j:j + vl]
+                    with (tr.span("prefill_chunk", cat="prefill",
+                                  uid=req.uid, slot=i, offset=j, valid=vl)
+                          if tr is not None else _NO_SPAN):
+                        view = self._prefill_paged(
+                            self.params, view, jnp.asarray(buf),
+                            np.int32(j), np.int32(vl), page_row)
+                    self.prefill_dispatches += 1
+                    self.prefill_tokens += vl
+                # merge eagerly in python: pooled leaves pass through BY
+                # REFERENCE (the pool was updated in place via donation);
+                # unpooled leaves are written into slot i of the dense half
+                new_caches = jax.tree.map(
+                    lambda cc, v, bax, pooled: v if pooled else
+                    jax.lax.dynamic_update_slice_in_dim(
+                        cc, v.astype(cc.dtype), i, axis=bax),
+                    caches, view, self._axes, self._pooled)
+            self.prefill_s += (time.perf_counter_ns() - t0) / 1e9
+            live = self._set_slot(i, req, prompt, new_caches,
+                                  page_table=new_pt)
 
         # register freshly-written shareable blocks for future prompts
         if self._prefix is not None:
@@ -1135,7 +1132,9 @@ class ServingEngine:
                 self._admit_one(free[0], req)
             self.queue.popleft()
             if self.tracer is not None:
+                # drawn from arrival, so the queueing before admit shows
                 self.tracer.async_begin("request", id=req.uid,
+                                        ts_ns=req.arrival_ns,
                                         mode=req.decoding, width=width)
                 if req.done:  # max_new_tokens <= 0: retires at admission
                     self.tracer.async_end("request", id=req.uid, tokens=0)
@@ -1170,29 +1169,48 @@ class ServingEngine:
         # program with the matching static flags
         has_beam = any(self._slot_kind[i] == decoding.BEAM for i in occupied)
         has_spec = any(self._slot_kind[i] == decoding.SPEC for i in occupied)
+        tr = self.tracer
         t0 = time.perf_counter_ns()
+        with (tr.span("decode_chunk", cat="decode", steps=self.decode_chunk,
+                      active_slots=occ, occupancy=occ / self.max_batch,
+                      has_beam=has_beam, has_spec=has_spec)
+              if tr is not None else _NO_SPAN):
+            with (tr.span("decode_dispatch", cat="decode")
+                  if tr is not None else _NO_SPAN):
+                if not (has_beam or has_spec):
+                    self.state, toks, valid = self._decode(self.params,
+                                                           self.state)
+                    fetch = (toks, valid, self.state.active)
+                else:
+                    fn = self._get_decode(has_beam, has_spec)
+                    dp = self.draft_params if has_spec else self.params
+                    self.state, toks, valid, parent = fn(self.params, dp,
+                                                         self.state)
+                    fetch = (toks, valid, parent, self.state.active,
+                             self.state.beam_score, self.state.spec_steps,
+                             self.state.spec_accepted)
+            with (tr.span("decode_sync", cat="decode")
+                  if tr is not None else _NO_SPAN):
+                got = jax.device_get(fetch)  # THE once-per-chunk sync
+            t1 = time.perf_counter_ns()  # the timestamp the sync earned
         if not (has_beam or has_spec):
-            self.state, toks, valid = self._decode(self.params, self.state)
-            toks, valid, alive = jax.device_get(
-                (toks, valid, self.state.active))  # THE once-per-chunk sync
+            toks, valid, alive = got
             toks, valid = toks[:, :, None], valid[:, :, None]  # [N, B, 1]
             parent = scores = sst = sacc = None
         else:
-            fn = self._get_decode(has_beam, has_spec)
-            dp = self.draft_params if has_spec else self.params
-            self.state, toks, valid, parent = fn(self.params, dp, self.state)
-            toks, valid, parent, alive, scores, sst, sacc = jax.device_get(
-                (toks, valid, parent, self.state.active,
-                 self.state.beam_score, self.state.spec_steps,
-                 self.state.spec_accepted))  # still ONE sync per chunk
-        t1 = time.perf_counter_ns()  # the timestamp the sync already earned
+            toks, valid, parent, alive, scores, sst, sacc = got
         self.decode_syncs += 1
         self._h_chunk_s.observe((t1 - t0) / 1e9)
-        if self.tracer is not None:
-            self.tracer.complete(
-                "decode_chunk", t0, t1, cat="decode", steps=self.decode_chunk,
-                active_slots=occ, occupancy=occ / self.max_batch,
-                has_beam=has_beam, has_spec=has_spec)
+        with tr.span("emit", cat="decode") if tr is not None else _NO_SPAN:
+            self._emit(occupied, has_beam, t1, toks, valid, parent, alive,
+                       scores, sst, sacc)
+        return True
+
+    def _emit(self, occupied, has_beam, t_sync, toks, valid, parent, alive,
+              scores, sst, sacc):
+        """After a decode sync: append the returned tokens to their
+        requests, stamp first tokens with the sync's time, retire finished
+        slots and beam groups."""
         if self.paged and self._pending_keys:
             # every pending divergence entry's origin slot just ran its
             # first decode chunk, writing the entry's last position: promote
@@ -1217,6 +1235,11 @@ class ServingEngine:
                     if valid[n, i, j]:
                         self.slots[i].output.append(int(toks[n, i, j]))
                         self.decode_tokens += 1
+        for i in occupied:
+            req = self.slots[i]
+            if req.first_token_ns is None and (req.output
+                                                or self._beam_hist[i]):
+                req.first_token_ns = t_sync
         retired = []
         for i in occupied:
             if alive[i]:
@@ -1280,7 +1303,6 @@ class ServingEngine:
                 self.state,
                 page_table=self.state.page_table
                 .at[jnp.asarray(retired)].set(0))
-        return True
 
     def run_to_completion(self, max_ticks: int = 10000):
         ticks = 0
@@ -1411,6 +1433,34 @@ class ServingEngine:
             s = rec.summary()
             out["dispatch"] = {k: s[k] for k in
                                ("decisions", "tuned", "heuristic", "forced")}
+        return out
+
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """{HLO module name: {instruction: scope label}} for the programs a
+        pool of greedy slots runs: the decode chunk, the prefill chunk and
+        (dense) the cache merge. Each is compiled once more with the
+        engine's own arguments and read by ``repro.obs.scopes``, so a
+        device trace of this engine can put its ops' time down to the
+        ``mpgemm``, ``attention`` and ``lm_head`` scopes."""
+        tok = jnp.zeros((1, self.prefill_chunk), jnp.int32)
+        at = (np.int32(0), np.int32(1))
+        progs = [(self._decode, (self.params, self.state))]
+        if self.paged:
+            view = jax.tree.map(lambda c, z, pooled: c if pooled else z,
+                                self.state.caches, self._zero_slot,
+                                self._pooled)
+            row = jnp.zeros((1, self.blocks_per_slot), jnp.int32)
+            progs.append((self._prefill_paged,
+                          (self.params, view, tok, *at, row)))
+        else:
+            progs += [(self._prefill, (self.params, self._zero_slot, tok,
+                                       *at)),
+                      (self._merge, (self.state.caches, self._zero_slot,
+                                     at[0]))]
+        out = {}
+        for fn, args in progs:
+            text = fn.lower(*args).compile().as_text()
+            out[scopes.module_name(text)] = scopes.op_scopes(text)
         return out
 
     def metrics_snapshot(self) -> dict:

@@ -12,13 +12,21 @@ The contracts under test:
     spec/beam configurations;
   * telemetry never changes engine behaviour: tokens and sync counts are
     identical with and without a tracer;
+  * the engine's spans are live, nest, and open each request's async span
+    at its arrival; every request is stamped arrival <= admit <= first
+    token;
+  * the named scopes label every contraction of the compiled decode
+    program and change nothing in it but its metadata;
   * heartbeat/interval math runs on the monotonic clock (wall-clock jumps
     must not fire timeouts).
 """
 
+import contextlib
 import json
 import math
+import re
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -28,6 +36,7 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.models import api
 from repro.obs import dispatch as dispatch_obs
+from repro.obs import scopes
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                export_stats)
 from repro.obs.trace import Tracer, load_trace, validate_chrome_trace
@@ -500,6 +509,191 @@ def test_metrics_snapshot_and_prometheus(tl):
     assert "engine_decode_chunk_seconds_count" in txt
     assert "blockpool_blocks_in_use" in txt
     json.dumps(snap)  # json-able end to end
+
+
+ENGINE_SPANS = {"admit", "prefill_chunk", "decode_chunk", "decode_dispatch",
+                "decode_sync", "emit"}
+
+
+def _inside(inner, outer):
+    return (outer["ts"] <= inner["ts"] + 1e-3 and inner["ts"] + inner["dur"]
+            <= outer["ts"] + outer["dur"] + 1e-3)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_engine_spans_nest_and_requests_open_at_arrival(tl, paged):
+    """Live engine spans: dispatch and sync inside their decode chunk,
+    prefill dispatches inside their admission, one emit per chunk; each
+    request's one async span opens at its arrival, before its admission."""
+    cfg, params = tl
+    tracer = Tracer(annotate_xla=False)
+    kw = dict(cache_block_size=8, prefix_cache=True) if paged else {}
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=64, decode_chunk=4,
+                        prefill_chunk=4, tracer=tracer, **kw)
+    reqs = [Request(uid=i, prompt=p, max_new_tokens=6)
+            for i, p in enumerate(_prompts(cfg, 3))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_to_completion()
+
+    evs = tracer.chrome_trace()["traceEvents"]
+    summary = validate_chrome_trace(evs)
+    assert summary["by_phase"]["b"] == summary["by_phase"]["e"] == len(reqs)
+    spans = [e for e in evs if e["ph"] == "X"]
+    assert {e["name"] for e in spans} == ENGINE_SPANS
+    by = {n: [e for e in spans if e["name"] == n] for n in ENGINE_SPANS}
+    assert (len(by["decode_dispatch"]) == len(by["decode_sync"])
+            == len(by["emit"]) == len(by["decode_chunk"])
+            == eng.decode_syncs)
+    for child, parent in (("decode_dispatch", "decode_chunk"),
+                          ("decode_sync", "decode_chunk"),
+                          ("prefill_chunk", "admit")):
+        for c in by[child]:
+            assert any(_inside(c, p) for p in by[parent]), (child, c)
+    for e in by["emit"]:  # after its chunk's sync, never inside a chunk
+        assert not any(_inside(e, c) for c in by["decode_chunk"])
+    admits = {e["args"]["uid"]: e for e in by["admit"]}
+    assert set(admits) == {r.uid for r in reqs}
+    for r in reqs:
+        b = next(e for e in evs if e["ph"] == "b" and e["id"] == r.uid)
+        assert b["ts"] == pytest.approx(tracer._us(r.arrival_ns))
+        assert b["ts"] <= admits[r.uid]["ts"]
+        assert admits[r.uid]["ts"] == pytest.approx(
+            tracer._us(r.admit_ns), abs=1e3)
+
+
+@pytest.mark.parametrize("name", sorted(ENGINE_CONFIGS))
+def test_request_timestamps_are_ordered(tl, name):
+    """arrival <= admit <= first token for every finished request, in every
+    engine configuration; a caller's own arrival time is kept."""
+    cfg, params = tl
+    kw, dec, _ = ENGINE_CONFIGS[name]
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=64, decode_chunk=4,
+                        prefill_chunk=4, **kw)
+    given = time.perf_counter_ns() - 10**6
+    reqs = [Request(uid=i, prompt=p, max_new_tokens=6, decoding=dec)
+            for i, p in enumerate(_prompts(cfg, 3))]
+    reqs[0].arrival_ns = given
+    for r in reqs:
+        eng.submit(r)
+    t_end = time.perf_counter_ns()
+    eng.run_to_completion()
+    assert reqs[0].arrival_ns == given
+    for r in reqs:
+        assert r.done and r.output
+        assert r.arrival_ns <= t_end
+        assert r.arrival_ns <= r.admit_ns <= r.first_token_ns
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_scopes_label_every_contraction_of_the_decode_program(tl, paged):
+    """In the compiled decode program every dot is in a scope: the seven
+    projections' contractions in ``mpgemm`` (one scanned layer body), the
+    LM head's in ``lm_head`` (a float head: the configuration leaves it
+    unquantized), the scores and values in ``attention``; the CW build and
+    the tables are in their sub-scopes. The engine maps its prefill
+    program too, dense or paged."""
+    cfg, params = tl
+    kw = dict(cache_block_size=8) if paged else {}
+    eng = ServingEngine(cfg, params, max_batch=2, max_seq=64, decode_chunk=2,
+                        prefill_chunk=4, **kw)
+    text = eng._decode.lower(eng.params, eng.state).compile().as_text()
+    labels = scopes.op_scopes(text)
+    maps = eng.op_scopes()
+    assert maps["jit__decode_chunk_impl"] == labels
+    prefill = "jit__paged_prefill_impl" if paged else "jit__prefill_chunk_impl"
+    assert "mpgemm" in maps[prefill].values()
+    dots = [m.group(1) for m in re.finditer(
+        r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = \S+ dot\(", text, re.M)]
+    got = sorted(labels[d] for d in dots)
+    assert got == sorted(["mpgemm"] * 7 + ["lm_head"]
+                         + ["attention"] * 2), got
+    assert {"mpgemm/cw", "mpgemm/table", "other"} <= set(labels.values())
+
+
+HLO_FUSION = """HloModule jit_f, is_scheduled=true
+
+%fused_computation (p0: f32[4], p1: f32[4]) -> f32[4] {
+  %p0 = f32[4]{0} parameter(0)
+  %p1 = f32[4]{0} parameter(1)
+  %convert.1 = f32[4]{0} convert(%p0), metadata={op_name="jit(f)/while/body/checkpoint/mpgemm/cw/convert_element_type"}
+  ROOT %add.2 = f32[4]{0} add(%convert.1, %p1), metadata={op_name="jit(f)/while/body/add"}
+}
+
+%fused_computation.1 (p0.1: f32[4]) -> f32[4] {
+  %p0.1 = f32[4]{0} parameter(0)
+  %exp.3 = f32[4]{0} exponential(%p0.1), metadata={op_name="jit(f)/attention/exp"}
+  ROOT %mul.4 = f32[4]{0} multiply(%exp.3, %exp.3), metadata={op_name="jit(f)/lm_head/mul"}
+}
+
+%fused_computation.2 (p0.2: f32[4]) -> f32[4] {
+  %p0.2 = f32[4]{0} parameter(0)
+  %fusion.9 = f32[4]{0} fusion(%p0.2), kind=kLoop, calls=%fused_computation.1
+  ROOT %dot.5 = f32[4]{0} multiply(%fusion.9, %p0.2), metadata={op_name="jit(f)/lm_head/mpgemm/dot_general"}
+}
+
+ENTRY %main (a: f32[4], b: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %b = f32[4]{0} parameter(1)
+  %add_fusion = f32[4]{0} fusion(%a, %b), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/while/body/add"}
+  %mul_fusion = f32[4]{0} fusion(%add_fusion), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(f)/lm_head/mul"}
+  %outer_fusion = f32[4]{0} fusion(%mul_fusion), kind=kOutput, calls=%fused_computation.2
+  ROOT %copy.6 = f32[4]{0} copy(%outer_fusion)
+}
+"""
+
+
+def test_fusion_takes_the_scope_of_what_it_fuses():
+    """A fusion's metadata is its root's: it is labelled by everything it
+    fuses, nested fusions included, mpGEMM over attention over the LM
+    head's other ops."""
+    labels = scopes.op_scopes(HLO_FUSION)
+    assert labels["add_fusion"] == "mpgemm/cw"        # root outside a scope
+    assert labels["mul_fusion"] == "attention"        # attention over lm_head
+    assert labels["outer_fusion"] == "lm_head/mpgemm"  # through a nested one
+    assert labels["copy.6"] == "other"
+    assert labels["convert.1"] == "mpgemm/cw" and labels["add.2"] == "other"
+    assert scopes.module_name(HLO_FUSION) == "jit_f"
+    assert scopes.scope_label("jit(f)/lm_head/mpgemm/table/sub") \
+        == "lm_head/mpgemm/table"
+
+
+def _canonical(text):
+    """Compiled HLO without metadata, frame tables and name numbering."""
+    text = "\n".join(line for line in text.splitlines() if not re.match(
+        r"(\d+ |FileNames$|FunctionNames$|FileLocations$|StackFrames$)",
+        line))
+    text = re.sub(r",?\s*metadata=\{[^}]*\}", "", text)
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_scopes_leave_the_compiled_program_unchanged(tl, monkeypatch,
+                                                     program):
+    """The scopes are metadata: the optimized program is the same without
+    them once metadata and instruction numbering are set aside."""
+    cfg, params = tl
+
+    def compiled():
+        eng = ServingEngine(cfg, params, max_batch=2, max_seq=64,
+                            decode_chunk=2, prefill_chunk=4)
+        if program == "decode":
+            low = eng._decode.lower(eng.params, eng.state)
+        else:
+            low = eng._prefill.lower(eng.params, eng._zero_slot,
+                                     jnp.zeros((1, 4), jnp.int32),
+                                     np.int32(0), np.int32(1))
+        return low.compile().as_text()
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled()
+    assert "mpgemm/" in scoped and "mpgemm/" not in bare
+    assert _canonical(scoped) == _canonical(bare)
 
 
 # ---------------------------------------------------------------------------
