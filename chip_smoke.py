@@ -12,7 +12,8 @@ One chip, four phases, one line each:
     shapes, compiled for the chip (``tpu_custom_call`` in the program),
     against ``x @ dequantize(W).T`` in float32 at precision "highest";
   * lut_xla and lut_pallas serving — ``ServingEngine`` built the way
-    ``python -m repro.launch.serve`` builds it, a few greedy requests, then
+    ``python -m repro.launch.serve`` builds it (both decode programs hold
+    the Pallas lookup kernel), a few greedy requests, then
     every generated token checked against one plain ``api.forward`` over
     the whole prompt+output sequence (no engine, cache or chunking) on the
     same packed weights.
@@ -243,10 +244,10 @@ def serve_phase(flags, mode: str, params=None, custom_call=True):
         check(r.done and len(r.output) == args.max_new,
               f"{mode}: request {r.uid} got {len(r.output or [])} of "
               f"{args.max_new} tokens")
-    if custom_call and mode == "lut_pallas":
+    if custom_call:  # lut_xla too: its contraction is the lookup kernel
         text = eng._decode.lower(eng.params, eng.state).compile().as_text()
         check("tpu_custom_call" in text,
-              "the lut_pallas decode program holds no Pallas kernel")
+              f"the {mode} decode program holds no Pallas kernel")
     ref_cfg = cfg.with_quant(mpgemm_mode="lut_xla")
     agree, total, worst = reference_check(ref_cfg, eng.params, reqs, mode)
     tokens = sum(len(r.output) for r in reqs)

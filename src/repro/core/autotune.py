@@ -103,7 +103,7 @@ def sanitize_config(cfg: TunedConfig, m: int, n: int, g: int, k_group: int,
     try:
         bm, bn, bg = int(cfg.block_m), int(cfg.block_n), int(cfg.block_g)
         fusion = str(cfg.fusion)
-    except (TypeError, ValueError):
+    except (TypeError, ValueError, OverflowError):  # None, "x", inf
         return None
     if fusion not in ("fused", "staged") or bm <= 0 or bn <= 0 or bg <= 0:
         return None

@@ -23,10 +23,11 @@ from repro.roofline import hw
 
 __all__ = ["LMMADescriptor", "TileSchedule", "schedule_tiles", "lmma_name",
            "fused_tile_bytes", "select_fusion", "align_blocks", "VMEM_BYTES",
-           "TILE_BUDGET"]
+           "TILE_BUDGET", "lookup_vmem_limit"]
 
-# the scoped-VMEM limit every pallas_call passes to the compiler: the whole
-# VMEM of the target chip (one TensorCore per v5e chip)
+# the whole VMEM of the target chip (one TensorCore per v5e chip): the
+# scoped-VMEM limit of every pallas_call but the lookup kernel's, which asks
+# for what its blocks need (lookup_vmem_limit)
 VMEM_BYTES = hw.spec(hw.TARGET_KIND).vmem_bytes
 # what the scheduler lets the double-buffered blocks take; the other half is
 # for in-kernel temporaries (unpacked fields, CW tiles) and compiler scratch
@@ -111,6 +112,25 @@ def _tile_bytes(bm, bn, bg, desc: LMMADescriptor) -> Tuple[int, int, int]:
     cw = bn * bg * e                                            # int8 CW expansion
     acc = bm * bn * _DTYPE_BYTES[desc.acc_dtype]
     return table, weights + cw, acc
+
+
+def lookup_vmem_limit(bm: int, bn: int, bg: int, k_group: int, planes: int,
+                      entry_bytes: int = 1) -> int:
+    """Scoped VMEM to ask for one lookup-kernel call: twice the scheduler's
+    working set of its blocks (double-buffered table and code blocks, the
+    CW expansion, the accumulator), so the in-kernel temporaries fit too;
+    at most the chip's VMEM. ``entry_bytes`` is the size of the table and
+    CW entries the kernel contracts: 1 on its int8 path, 4 where it works
+    in f32 (float or per-group tables).
+
+    A Pallas call holds its whole limit while it runs. Asking for all of
+    VMEM left none to the XLA program around the kernel: the decode layer
+    scan then moved each layer's KV cache slice out to HBM and back."""
+    desc = LMMADescriptor(m=bm, n=bn, k=bg * k_group, w_bits=planes,
+                          k_group=k_group, table_bits=8 * entry_bytes)
+    t, w, a = _tile_bytes(bm, bn, bg, desc)
+    w += (entry_bytes - 1) * bn * bg * (1 << (k_group - 1))
+    return min(VMEM_BYTES, 2 * (2 * (t + w) + a))
 
 
 def schedule_tiles(desc: LMMADescriptor,

@@ -5,9 +5,19 @@ low-bit weights.  Modes:
 
   * ``"dequant"``     — unpack→upcast→GEMM (paper Fig. 2b baseline; what a
                         stock accelerator must do).
-  * ``"lut_xla"``     — LUT-based: DFG-split table precompute + single
-                        ``T @ CW`` GEMM (TPU-native lookup, DESIGN.md §2);
-                        with ``table_quant='per_row'`` the GEMM runs int8.
+  * ``"lut_xla"``     — LUT-based: DFG-split table precompute by XLA + one
+                        ``T @ CW`` contraction (DESIGN.md §2); with
+                        ``table_quant='per_row'`` it runs int8. Where CW
+                        lives depends on what the call can observe:
+                          - TPU, per-row int8 table, packed store, one
+                            device: the lookup kernel over the packed planes
+                            (``kernels.ops.lut_mpgemm(table=...)``) builds
+                            CW only as VMEM tiles;
+                          - CPU: the engine hoists CW once at load
+                            (``qw.cw``), and the contraction reads it;
+                          - a multi-device plan or a plane-sliced draft
+                            view: CW is built by XLA from the planes.
+                        The arithmetic is the same on every branch.
   * ``"lut_pallas"``  — the Pallas LUT Tensor Core kernel (kernels/); the
                         ``fusion`` knob picks the fused single-kernel
                         precompute→lookup pipeline (table stays in VMEM,
@@ -71,10 +81,35 @@ def precompute_tables(x, k_group: int = 4, table_quant: Optional[str] = "per_row
                                 table_quant)
 
 
-def _lut_xla(x2d, qw: QuantizedWeight, table_quant, table: Optional[Table]):
-    from repro.kernels import ref  # local import to avoid cycles
+def _packed_lookup(qw: QuantizedWeight, table_quant) -> bool:
+    """Whether lut_xla's contraction runs as the lookup kernel over the
+    packed planes: on the TPU, for the int8 per-row table (whose int32
+    accumulation and epilogue the kernel reproduces bit for bit), against a
+    packed store (not the CW store the CPU engine hoists, nor a plane-sliced
+    view, which the kernel refuses), with no plan of several devices (a
+    custom call there would make the partitioner gather the weights)."""
+    if jax.default_backend() != "tpu" or table_quant != "per_row":
+        return False
+    if qw.cw is not None or qw.is_plane_sliced:
+        return False
+    from repro.distributed.sharding import current_plan
+    plan = current_plan()
+    return plan is None or plan.mesh.size == 1
 
-    return ref.ref_lut_mpgemm_matmul(x2d, qw, table_quant=table_quant, table=table)
+
+def _lut_xla(x2d, qw: QuantizedWeight, table_quant, table: Optional[Table],
+             interpret):
+    from repro.kernels import ops, ref  # local import to avoid cycles
+
+    if not _packed_lookup(qw, table_quant):
+        return ref.ref_lut_mpgemm_matmul(x2d, qw, table_quant=table_quant,
+                                         table=table)
+    if table is None:
+        with jax.named_scope("table"):
+            table = precompute_table(x2d, qw.k_group, table_quant)
+    return ops.lut_mpgemm(x2d, qw, table_quant=table_quant, table=table,
+                          fusion="staged", requested="lut_xla",
+                          interpret=bool(interpret))
 
 
 def _lut_pallas(x2d, qw: QuantizedWeight, table_quant, table: Optional[Table],
@@ -103,7 +138,9 @@ def mpgemm(
     "staged" materializes it between two kernels, "auto" lets the LMMA tile
     scheduler decide from the VMEM budget, "tuned" uses the persistent
     measured-time autotune cache (auto on a miss). Ignored when ``table=``
-    is supplied — a shared table is by definition staged.
+    is supplied — a shared table is by definition staged. ``interpret``
+    runs the Pallas kernels in interpret mode (default: off the TPU); on
+    lut_xla it matters only where the lookup kernel runs.
 
     Every mode traces under the named scope ``mpgemm`` (sub-scopes ``table``
     and ``cw`` for the table precompute and the CW build), so the compiled
@@ -128,7 +165,7 @@ def mpgemm(
             out = jnp.dot(x2d.astype(jnp.bfloat16), w.T,
                           preferred_element_type=jnp.float32)
         elif mode == "lut_xla":
-            out = _lut_xla(x2d, qw, table_quant, table)
+            out = _lut_xla(x2d, qw, table_quant, table, interpret)
         else:  # lut_pallas
             if interpret is None:
                 interpret = jax.default_backend() != "tpu"

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.lmma import VMEM_BYTES
+from repro.core.lmma import lookup_vmem_limit
 from repro.core.packing import LANES, chunk_groups
 from repro.kernels.table_precompute import for_each, lane_tile
 
@@ -225,7 +225,9 @@ def lut_mpgemm_pallas(
         scratch_shapes=[pltpu.VMEM((block_m, block_n), acc_dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_BYTES),
+            vmem_limit_bytes=lookup_vmem_limit(
+                block_m, block_n, block_g, k_group, planes,
+                1 if scale_mode == "per_row" else 4)),
         interpret=interpret,
         name="lut_mpgemm",
     )(*args)

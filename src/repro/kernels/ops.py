@@ -43,12 +43,16 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-def pick_blocks(m, n, g, k_group, planes, max_bm=256, max_bn=512, max_bg=512):
-    """Block shapes: scheduler-elongated, capped, tile-aligned."""
+def pick_blocks(m, n, g, k_group, planes, max_bm=256, max_bg=512):
+    """Block shapes: scheduler-elongated, capped, tile-aligned. bn is the
+    scheduler's, uncapped (up to 2048): on a v5e the lookup kernel at
+    (32, 2048, 128) was within 4.4% of the fastest block at every
+    projection shape of paper-bitnet-3b and qwen2-72b, at 8 and 32 rows,
+    where a cap of 512 was up to 14.5% slower (PERF.md)."""
     desc = LMMADescriptor(m=m, n=n, k=g * k_group, w_bits=planes, k_group=k_group)
     ts = schedule_tiles(desc)
     return align_blocks(m, n, g, k_group, planes, min(ts.bm, max_bm),
-                        min(ts.bn, max_bn), min(ts.bg, max_bg))
+                        ts.bn, min(ts.bg, max_bg))
 
 
 def _closed_form_row_scale(a: jax.Array, g: int, k_group: int) -> jax.Array:
@@ -114,7 +118,8 @@ def plan_local_shape(m, n):
 
 def resolve_dispatch(m, n, g, k_group, planes, *, fusion="auto",
                      block_m=None, block_n=None, block_g=None,
-                     table_quant: Optional[str] = "per_row"):
+                     table_quant: Optional[str] = "per_row",
+                     requested: Optional[str] = None):
     """Trace-time dispatch decision for one mpGEMM shape.
 
     Returns the fully-resolved ``(fusion, bm, bn, bg)`` the wrappers will
@@ -130,9 +135,13 @@ def resolve_dispatch(m, n, g, k_group, planes, *, fusion="auto",
         degrades to ``"auto"``.
       * ``"auto"``   — clamp blocks, then the LMMA VMEM-fit heuristic.
       * ``"fused"``/``"staged"`` — forced, blocks clamped as usual.
+
+    ``requested`` names the caller's policy in the dispatch recorder
+    (default: ``fusion`` as asked); lut_xla's lookup kernel logs
+    ``"lut_xla"``.
     """
     m, n = plan_local_shape(m, n)
-    requested = fusion
+    requested = requested or fusion
     source = "forced"
     if fusion == "tuned":
         tc = autotune.lookup_tuned(m, n, g, k_group, planes,
@@ -280,6 +289,7 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
                fusion: str = "auto",
                block_m: Optional[int] = None, block_n: Optional[int] = None,
                block_g: Optional[int] = None,
+               requested: Optional[str] = None,
                interpret: bool = False) -> jax.Array:
     """LUT mpGEMM via the Pallas kernels.
 
@@ -292,7 +302,8 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
     measured-time autotune cache (``core.autotune``) and falls back to
     "auto" on a miss. A caller-supplied ``table=`` (the cross-consumer
     amortization of §3.1.1) always implies staged — the table already
-    exists.
+    exists. ``requested`` is the policy the dispatch recorder logs
+    (``resolve_dispatch``).
     """
     if fusion not in FUSION_MODES:
         raise ValueError(f"fusion {fusion!r} not in {FUSION_MODES}")
@@ -302,7 +313,8 @@ def lut_mpgemm(x: jax.Array, qw: QuantizedWeight, *,
     planes = qw.num_planes
     fusion, bm, bn, bg = resolve_dispatch(
         m, qw.n, g, qw.k_group, planes, fusion=fusion, block_m=block_m,
-        block_n=block_n, block_g=block_g, table_quant=table_quant)
+        block_n=block_n, block_g=block_g, table_quant=table_quant,
+        requested=requested)
     if table is None and fusion == "fused":
         return fused_lut_mpgemm(
             x, qw, table_quant=table_quant, block_m=bm, block_n=bn,

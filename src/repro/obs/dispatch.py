@@ -34,7 +34,8 @@ class DispatchRecord:
     kind: str            # "dispatch" (resolve_dispatch) | "select_fusion"
     key: str             # autotune.shape_key / lmma descriptor name
     fusion: str          # resolved fusion actually dispatched
-    requested: str       # caller policy: auto | tuned | fused | staged
+    requested: str       # caller policy: auto | tuned | fused | staged,
+    #                      or lut_xla (its lookup kernel over packed planes)
     source: str          # "tuned" (cache hit) | "heuristic" | "forced"
     block_m: int = 0
     block_n: int = 0
@@ -70,7 +71,8 @@ class DispatchRecorder:
         return sorted(recs, key=lambda r: (r.kind, r.key, r.fusion))
 
     def summary(self) -> dict:
-        """Aggregate for stats()/bench JSON: decisions by source + the full
+        """Aggregate for stats()/bench JSON: decisions by source, the
+        mpGEMM calls traced onto lut_xla's lookup kernel, and the full
         per-shape table."""
         recs = self.records()
         disp = [r for r in recs if r.kind == "dispatch"]
@@ -79,6 +81,7 @@ class DispatchRecorder:
             "tuned": sum(1 for r in disp if r.source == "tuned"),
             "heuristic": sum(1 for r in disp if r.source == "heuristic"),
             "forced": sum(1 for r in disp if r.source == "forced"),
+            "lut_xla": sum(r.count for r in disp if r.requested == "lut_xla"),
             "records": [r.as_dict() for r in recs],
         }
 

@@ -1432,7 +1432,8 @@ class ServingEngine:
         if rec is not None:
             s = rec.summary()
             out["dispatch"] = {k: s[k] for k in
-                               ("decisions", "tuned", "heuristic", "forced")}
+                               ("decisions", "tuned", "heuristic", "forced",
+                                "lut_xla")}
         return out
 
     def op_scopes(self) -> Dict[str, Dict[str, str]]:

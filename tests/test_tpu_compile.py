@@ -25,6 +25,8 @@ from repro.core.mpgemm import mpgemm
 from repro.kernels import ops
 
 SHAPES = [(3200, 3200), (3200, 8640), (8640, 3200)]  # (K, N)
+QWEN_SHAPES = [(8192, 8192), (8192, 1024), (8192, 29568), (29568, 8192),
+               (8192, 152064)]  # q/o, k/v, gate/up, down, LM head
 ROWS = [8, 32]
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
 
@@ -58,10 +60,10 @@ def _on(sharding, tree):
         tree)
 
 
-def _ternary(sharding, k, n):
+def _ternary(sharding, k, n, scheme="ternary"):
     w = jax.ShapeDtypeStruct((n, k), jnp.float32)
     return _on(sharding, jax.eval_shape(
-        lambda w: Q.quantize(w, 2, 4, "ternary"), w))
+        lambda w: Q.quantize(w, 2, 4, scheme), w))
 
 
 def _compile(fn, *args):
@@ -85,20 +87,36 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, k, n, m):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_lut_xla_mpgemm_compiles_for_v5e(one_chip):
-    """The default mode: table precompute + one int8 T @ CW GEMM in XLA."""
-    x = jax.ShapeDtypeStruct((8, 3200), jnp.float32, sharding=one_chip)
-    c = _compile(lambda x, q: mpgemm(x, q, mode="lut_xla",
-                                     table_quant="per_row"),
-                 x, _ternary(one_chip, 3200, 8640))
-    assert "tpu_custom_call" not in c.as_text()
+def _lut_xla(one_chip, m, k, n, scheme):
+    x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
+    return _compile(lambda x, q: mpgemm(x, q, mode="lut_xla",
+                                        table_quant="per_row"),
+                    x, _ternary(one_chip, k, n, scheme))
+
+
+def test_lut_xla_mpgemm_compiles_for_v5e(one_chip, monkeypatch):
+    """The default mode on the chip: the XLA table precompute, then the
+    lookup kernel over the packed planes (a Mosaic custom call) in place of
+    an XLA-built CW and int8 GEMM."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = _lut_xla(one_chip, 8, 3200, 8640, "ternary")
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("k,n", QWEN_SHAPES)
+def test_lut_xla_qwen_shapes_compile_for_v5e(one_chip, monkeypatch, k, n):
+    """qwen2-72b's W2 projections and its 152064-row LM head (a ragged last
+    N block) at decode rows: the lookup kernel lowers for the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = _lut_xla(one_chip, 8, k, n, "symmetric")
+    assert "tpu_custom_call" in c.as_text()
 
 
 @pytest.mark.parametrize("mode", ["lut_xla", "lut_pallas"])
 def test_bitnet_decode_step_fits_one_chip(one_chip, monkeypatch, mode):
     """The engine's greedy decode chunk at full paper-bitnet-3b width (26
     layers, 8 slots x 128 positions) compiles for one chip and fits its
-    16 GiB; the lut_pallas program carries the kernels."""
+    16 GiB; both programs carry the kernels (lut_xla its lookup kernel)."""
     from repro.configs import registry
     from repro.models import api
     from repro.serving.engine import ServingEngine
@@ -119,4 +137,4 @@ def test_bitnet_decode_step_fits_one_chip(one_chip, monkeypatch, mode):
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert live < HBM_BYTES, live
-    assert ("tpu_custom_call" in c.as_text()) == (mode == "lut_pallas")
+    assert "tpu_custom_call" in c.as_text()
