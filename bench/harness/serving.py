@@ -20,7 +20,9 @@ import time
 
 import numpy as np
 
+from bench.harness import attribution
 from bench.harness import device as D
+from bench.harness import readers
 from bench.harness import traffic as T
 from bench.harness import trace as TR
 
@@ -66,9 +68,9 @@ class Run:
     t_end: float
     tracks: list
     chunks: list
-    occupancy: tuple       # (chunks, sum of occupied/max_batch) in window
+    counters: dict         # registry_delta(...) of the engine over the window
     admits: dict           # uid -> admission start (traced runs)
-    trace: dict | None     # trace.reduce(...) of the traced part
+    trace: dict | None     # attribution.reduce(...) of the traced part
     trace_pc: tuple | None  # the traced part on the perf_counter clock
     compiles_in_window: int
 
@@ -360,6 +362,22 @@ def build(cell, seed: int, *, traced: bool, log=print):
     return eng, tracer
 
 
+def registry_delta(before: dict, after: dict) -> dict:
+    """What the engine's registry counted between two of its snapshots
+    (``MetricsRegistry.snapshot()``): name -> a counter's increase, or a
+    histogram's ``{"count": n, "total": sum}`` over the observations made
+    in between. Gauges, which count nothing, are left out."""
+    out = {}
+    for name, a in after["metrics"].items():
+        b = before["metrics"].get(name, {})
+        if a["type"] == "counter":
+            out[name] = a["value"] - b.get("value", 0)
+        elif a["type"] == "histogram":
+            out[name] = {"count": a["count"] - b.get("count", 0),
+                         "total": a["sum"] - b.get("sum", 0.0)}
+    return out
+
+
 def _admit_times(tracer, epoch_ns) -> dict:
     """uid -> start of the engine's ``admit`` span (perf_counter seconds)."""
     evs = tracer.chrome_trace()["traceEvents"]
@@ -367,6 +385,17 @@ def _admit_times(tracer, epoch_ns) -> dict:
     off = epoch_ns - mark["ts"] * 1e3
     return {ev["args"]["uid"]: (ev["ts"] * 1e3 + off) / 1e9
             for ev in evs if ev.get("name") == "admit"}
+
+
+def decode_scopes_per_step(run) -> list:
+    """``[[label, device seconds per decode step], ...]`` of the decode
+    program's scopes, longest first (empty where there are none)."""
+    scopes = readers.decode_scopes(run)
+    if scopes is None:
+        return []
+    seconds, steps = scopes
+    return sorted(([k, v / steps] for k, v in seconds.items()),
+                  key=lambda x: -x[1])[:10]
 
 
 def run_cell(cell, seed: int, seconds: float, *, traced: bool,
@@ -391,11 +420,14 @@ def run_cell(cell, seed: int, seconds: float, *, traced: bool,
         f"{time.perf_counter() - t_process:.2f} s")
     eng, tracer = build(cell, seed, traced=traced, log=log)
     requests = T.make_requests(mix, conf["vocab_size"], seed, seconds)
-    occ = eng.metrics.get("engine_slot_occupancy_ratio")
     t_setup_end = time.perf_counter()
     log(f"[setup] {t_setup_end - t_process:.1f} s to the first timed request")
+    scope_maps = None
+    if traced:  # after set-up: untraced runs, which give setup_s, skip it
+        scope_maps = eng.op_scopes()
+        log(f"[setup] op scopes {time.perf_counter() - t_setup_end:.2f} s")
 
-    compiles0, occ0 = _COMPILES[0], (occ.count, occ.total)
+    compiles0, registry0 = _COMPILES[0], eng.metrics.snapshot()
     epoch = time.perf_counter_ns()
     if tracer is not None:
         tracer.complete("bench.epoch", epoch, epoch)
@@ -414,16 +446,16 @@ def run_cell(cell, seed: int, seconds: float, *, traced: bool,
               peaks=D.PEAKS.get(info["kind"], {}), t_process=t_process,
               t_setup_end=t_setup_end, t0=t0, t_end=t_end, tracks=tracks,
               chunks=loop.chunks,
-              occupancy=(occ.count - occ0[0], occ.total - occ0[1]),
+              counters=registry_delta(registry0, eng.metrics.snapshot()),
               admits=_admit_times(tracer, epoch) if tracer else {},
               trace=None, trace_pc=trace_pc, compiles_in_window=compiles)
     memory = D.memory_peak(devs)
-    del eng, loop, tracer, occ
+    del eng, loop, tracer
     gc.collect()
 
     if tmp is not None:
         files = sorted(glob.glob(f"{tmp}/**/*.xplane.pb", recursive=True))
-        run.trace = TR.reduce(TR.load(files[-1]))
+        run.trace = attribution.reduce(TR.load(files[-1]), scope_maps)
         shutil.rmtree(tmp, ignore_errors=True)
 
     metrics = {}
@@ -446,7 +478,8 @@ def run_cell(cell, seed: int, seconds: float, *, traced: bool,
         dev["window_s"] = run.trace["window_s"]
         result["breakdown"] = {
             "device_ops": [list(x) for x in run.trace["device_ops"]],
-            "idle_gaps": [list(x) for x in run.trace["idle_gaps"]]}
+            "idle_gaps": [list(x) for x in run.trace["idle_gaps"]],
+            "scopes": decode_scopes_per_step(run)}
     result["check"] = {
         "max_logit_gap": {"value": gap, "limit": limit},
         "failed_requests": {"value": failed, "limit": 0},

@@ -10,23 +10,15 @@ whose host interval holds its midpoint; the steps that emitted a token
 count.
 """
 
-from bench.harness import trace
-from bench.harness.readers import to_perf_counter
+from bench.harness.readers import decode_chunks
 
 
 def read(run):
-    if run.trace is None:
-        return None
-    _, _, intervals = trace.program_seconds(run.trace, "_decode_chunk_impl")
     conf, peak = run.conf, run.peaks
     least = device = 0.0
-    chunks = list(run.chunks)
-    for s, e in intervals:
-        mid = to_perf_counter(run, (s + e) / 2)
-        c = next((c for c in chunks if c.t_start <= mid <= c.t_sync), None)
+    for (s, e), c in decode_chunks(run):
         if c is None or not c.emitted:
             continue
-        chunks.remove(c)
         device += (e - s) / 1e9
         for k in range(max(after - before for _, _, before, after
                            in c.emitted)):

@@ -1,8 +1,9 @@
 """Mean share of the engine's slots occupied at each decode chunk of the
 window, from the engine's own ``engine_slot_occupancy_ratio`` histogram
-(program counter)."""
+(program counter): its sum over its count, taken over the window from
+``Run.counters``."""
 
 
 def read(run):
-    chunks, total = run.occupancy
-    return 100.0 * total / chunks if chunks else None
+    h = run.counters.get("engine_slot_occupancy_ratio")
+    return 100.0 * h["total"] / h["count"] if h and h["count"] else None

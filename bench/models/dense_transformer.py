@@ -58,6 +58,20 @@ def layer_weights_count(conf) -> int:
     return sum(n * k for n, k, _ in proj_shapes(conf).values())
 
 
+def weights_applied(conf) -> int:
+    """Weights a decoded token applies: every layer's projections and the
+    LM head."""
+    return (conf["n_layers"] * layer_weights_count(conf)
+            + conf["vocab_size"] * conf["d_model"])
+
+
+def output_channels(conf) -> int:
+    """Output channels of those weights (one float32 scale each)."""
+    return (conf["n_layers"] * sum(n for n, _, _ in
+                                   proj_shapes(conf).values())
+            + conf["vocab_size"])
+
+
 # ---------------------------------------------------------------------------
 # the seeded checkpoint
 # ---------------------------------------------------------------------------
@@ -236,22 +250,18 @@ def reference_logits(conf, seed_words, tokens, rows, cols, act_bits=None):
 
 def weight_bytes(conf) -> int:
     """Projection and LM-head weights at ``weight_bits`` over the true K."""
-    n = conf["n_layers"] * layer_weights_count(conf)
-    n += conf["vocab_size"] * conf["d_model"]
-    return n * conf["quant"]["weight_bits"] // 8
+    return weights_applied(conf) * conf["quant"]["weight_bits"] // 8
 
 
 def step_fixed_bytes(conf) -> int:
     """Bytes every decode step reads whatever the batch: weights, their
     float32 per-channel scales, norm gains and biases."""
     pb = _DTYPE_BYTES[conf["param_dtype"]]
-    shapes = proj_shapes(conf).values()
-    scales = conf["n_layers"] * sum(n for n, _, _ in shapes)
-    scales += conf["vocab_size"]
     small = conf["n_layers"] * (2 * conf["d_model"]
-                                + sum(n for n, _, b in shapes if b))
+                                + sum(n for n, _, b in
+                                      proj_shapes(conf).values() if b))
     small += conf["d_model"]
-    return weight_bytes(conf) + 4 * scales + pb * small
+    return weight_bytes(conf) + 4 * output_channels(conf) + pb * small
 
 
 def kv_bytes_per_position(conf) -> int:
@@ -262,11 +272,9 @@ def kv_bytes_per_position(conf) -> int:
 def flops_per_token(conf, attended: int) -> int:
     """Decode FLOPs of one token that attends over ``attended`` positions:
     2 per weight applied (layers and LM head) plus QK^T and PV."""
-    w = conf["n_layers"] * layer_weights_count(conf)
-    w += conf["vocab_size"] * conf["d_model"]
     attn = (4 * conf["n_layers"] * conf["n_heads"] * conf["head_dim"]
             * attended)
-    return 2 * w + attn
+    return 2 * weights_applied(conf) + attn
 
 
 def decode_step_cost(conf, attended) -> tuple:
@@ -280,6 +288,27 @@ def decode_step_cost(conf, attended) -> tuple:
     nbytes = (step_fixed_bytes(conf) + kv * sum(attended)
               + len(attended) * (kv + pb * conf["d_model"]))
     return flops, nbytes
+
+
+def mpgemm_step_cost(conf, rows: int) -> tuple:
+    """(FLOPs, bytes) of one decode step's mpGEMMs (the seven projections
+    of every layer and the LM head) over ``rows`` live rows.
+
+    FLOPs: 2 per weight applied per row. Bytes: the weights at
+    ``weight_bits`` over the true K, their float32 scales, each row's int8
+    tables and its float32 outputs. A row's table over an input of width K
+    is the paper's symmetrized half table (section 3.1): K / g groups of
+    2^(g-1) int8 entries, g = ``k_group`` (2K bytes at g = 4). Projections
+    that read one input share its table (the paper's split-out precompute):
+    one for q/k/v, one for gate/up, one each for o, down and the head.
+    """
+    d, g = conf["d_model"], conf["quant"]["k_group"]
+    table_inputs = conf["n_layers"] * (
+        2 * d + conf["n_heads"] * conf["head_dim"] + conf["d_ff"]) + d
+    outputs = output_channels(conf)
+    nbytes = (weight_bytes(conf) + 4 * outputs
+              + rows * (table_inputs * (1 << (g - 1)) // g + 4 * outputs))
+    return 2 * rows * weights_applied(conf), nbytes
 
 
 def prefill_flops(conf, n_tokens: int) -> int:

@@ -70,6 +70,31 @@ def test_bitnet_decode_step_is_memory_bound():
     assert flops == 8 * (2 * 3_323_904_000 + 4 * 26 * 32 * 100 * 256)
 
 
+@pytest.mark.parametrize("conf,want", [
+    # bitnet: tables over 26 x (3200 q/k/v + 3200 o + 3200 gate/up
+    # + 8640 down) + 3200 head = 477,440 inputs, 2 int8 bytes each (4-groups
+    # of 8 entries); outputs 26 x 33,280 + 32,000 = 897,280 float32; weights
+    # 830,976,000 B at 2 bits over the true K (the program's packed planes,
+    # padded to whole chunks, are 910,458,880 B)
+    (BITNET, (3_323_904_000, 830_976_000 + 4 * 897_280,
+              2 * 477_440 + 4 * 897_280)),
+    # qwen-16: 16 x (8192 + 8192 + 8192 + 29568) + 8192 = 874,496 inputs;
+    # outputs 16 x 85,760 + 152,064 = 1,524,224
+    (QWEN, (15_288_238_080, 3_822_059_520 + 4 * 1_524_224,
+            2 * 874_496 + 4 * 1_524_224)),
+])
+def test_mpgemm_step_cost_by_hand(conf, want):
+    weights, fixed, per_row = want
+    assert M.mpgemm_step_cost(conf, 0) == (0, fixed)
+    assert M.mpgemm_step_cost(conf, 8) == (16 * weights, fixed + 8 * per_row)
+    # no rows: the weights and their scales alone
+    assert fixed == {BITNET["name"]: 834_565_120,
+                     QWEN["name"]: 3_828_156_416}[conf["name"]]
+    # at 8 rows bytes bound the step on a v5e
+    flops, nbytes = M.mpgemm_step_cost(conf, 8)
+    assert nbytes / 819e9 > flops / 197e12
+
+
 def test_prefill_flops_causal():
     n = 10
     want = (2 * 26 * 123_904_000 * n

@@ -149,7 +149,7 @@ def test_tpot_over_tokens_returned_by_the_end():
 def _run(tracks, t0=0.0, t_end=10.0):
     return serving.Run(cell=None, model=None, peaks={}, t_process=-5.0,
                        t_setup_end=-1.0, t0=t0, t_end=t_end, tracks=tracks,
-                       chunks=[], occupancy=(0, 0.0), admits={}, trace=None,
+                       chunks=[], counters={}, admits={}, trace=None,
                        trace_pc=None, compiles_in_window=0)
 
 
